@@ -31,6 +31,7 @@ from __future__ import annotations
 from typing import Mapping
 
 from jepsen_tpu import models as m
+from jepsen_tpu import obs, store
 from jepsen_tpu.checker import Checker, UNKNOWN
 from jepsen_tpu.checker import wgl_cpu
 from jepsen_tpu.obs import provenance as _prov
@@ -193,10 +194,9 @@ class Linearizable(Checker):
         """Write linear.svg next to the results — the reference renders
         the failed linearization via knossos.linear.report
         (checker.clj:207-210)."""
-        from jepsen_tpu import store
         from jepsen_tpu.checker.linear_svg import render_failure
 
-        if not (test.get("name") and test.get("start-time-str")):
+        if not store.has_test_dir(test):
             return  # no store configured (bare checker unit tests)
         svg = render_failure(history, result.get("op"), result.get("cause", ""))
         try:
@@ -212,19 +212,18 @@ class Linearizable(Checker):
     def check_batch(self, test, histories, opts):
         """Check many subhistories in ONE vmapped kernel ladder (used by
         independent.checker: per-key shards become the batch axis —
-        BASELINE config 4's shape).  CPU algorithms just loop."""
+        BASELINE config 4's shape).  CPU algorithms just loop.
+
+        The ladder call is one ``ladder.run`` span; the evidence bundles
+        are one ``checker.evidence`` span, whatever the number of
+        histories."""
         if self.algorithm in ("wgl", "sweep"):
             # headless: no per-key linear.svg (they would all land on the
             # same path and overwrite each other; independent.checker
             # writes per-key artifacts itself)
-            outs = [self._truncate(self._analyze(hh)) for hh in histories]
-            for hh, out in zip(histories, outs):
-                _prov.emit(test, hh, out, source="check_batch",
-                           model=self.model, checker="linearizable",
-                           opts=opts)
-            return outs
-        from jepsen_tpu.parallel import batch_analysis
-
+            return self._evidence(
+                test, histories, [self._analyze(hh) for hh in histories],
+                opts)
         # kernel-opts is shaped for wgl.analysis; forward only the keys
         # batch_analysis shares (capacity ladder, rounds, exact stage).
         batch_kw = {
@@ -237,23 +236,34 @@ class Linearizable(Checker):
         # run's store dir, honors the shared deadline, and resumes when
         # asked (jepsen_tpu.parallel.batch_analysis docstring).
         opts = opts or {}
-        results = batch_analysis(
-            self.model,
-            histories,
-            cpu_fallback=(self.algorithm == "competition"),
-            deadline=opts.get("deadline"),
-            checkpoint_dir=opts.get("checkpoint-dir"),
-            resume=bool(opts.get("resume?")),
-            **batch_kw,
-        )
-        outs = [self._truncate(r) for r in results]
-        # Rung admission can grow the result list past the input
-        # histories; emit bundles for the caller-supplied ones (joiner
-        # verdicts are the admission hook's to bundle — the serving
-        # layer does so per request).
-        for hh, out in zip(histories, outs):
-            _prov.emit(test, hh, out, source="check_batch",
-                       model=self.model, checker="linearizable", opts=opts)
+        with obs.span("ladder.run", histories=len(histories)):
+            # the first check pays for importing the ladder (and JAX)
+            from jepsen_tpu.parallel import batch_analysis
+
+            results = batch_analysis(
+                self.model,
+                histories,
+                cpu_fallback=(self.algorithm == "competition"),
+                deadline=opts.get("deadline"),
+                checkpoint_dir=opts.get("checkpoint-dir"),
+                resume=bool(opts.get("resume?")),
+                **batch_kw,
+            )
+        return self._evidence(test, histories, results, opts)
+
+    def _evidence(self, test, histories, results, opts):
+        """Truncate each result and build (with a store, write) its
+        evidence bundle, witness walk included.  Rung admission can grow
+        the result list past the input histories; bundles go to the
+        caller-supplied ones (joiner verdicts are the admission hook's to
+        bundle — the serving layer does so per request)."""
+        with obs.span("checker.evidence", histories=len(histories),
+                      stored=store.has_test_dir(test)):
+            outs = [self._truncate(r) for r in results]
+            for hh, out in zip(histories, outs):
+                _prov.emit(test, hh, out, source="check_batch",
+                           model=self.model, checker="linearizable",
+                           opts=opts)
         return outs
 
 

@@ -287,52 +287,48 @@ def greedy_walk(model: m.Model, history: Sequence[dict],
     cap = max_steps if max_steps is not None else 4 * len(history) + 64
     state, fok, fcr = model, frozenset(), empty
     b = steps = 0
-    with obs.span("wgl_cpu.greedy_walk") as sp:
-        while b < n_barriers:
-            _pos, i, open_ok, open_crashed = barriers[b]
-            if i in fok:
-                fok = fok - {i}
-                b += 1
+    while b < n_barriers:
+        _pos, i, open_ok, open_crashed = barriers[b]
+        if i in fok:
+            fok = fok - {i}
+            b += 1
+            continue
+        steps += 1
+        if steps > cap:
+            return None
+        # Greedy: fire the returning op itself first.
+        s2 = state.step(eff_ops[i])
+        if not m.is_inconsistent(s2):
+            state, fok = s2, fok | {i}
+            if record is not None:
+                record.append(eff_ops[i])
+            continue
+        # Enabling move: the first consistent open ok op, else the
+        # first available crashed group (same legality and order the
+        # DFS branches over — we just never come back).
+        for j in open_ok:
+            if j in fok or j == i:
                 continue
-            steps += 1
-            if steps > cap:
-                sp.set(completed=False, steps=steps)
-                return None
-            # Greedy: fire the returning op itself first.
-            s2 = state.step(eff_ops[i])
+            s2 = state.step(eff_ops[j])
             if not m.is_inconsistent(s2):
-                state, fok = s2, fok | {i}
+                state, fok = s2, fok | {j}
                 if record is not None:
-                    record.append(eff_ops[i])
-                continue
-            # Enabling move: the first consistent open ok op, else the
-            # first available crashed group (same legality and order the
-            # DFS branches over — we just never come back).
-            for j in open_ok:
-                if j in fok or j == i:
+                    record.append(eff_ops[j])
+                break
+        else:
+            for g, open_count in open_crashed:
+                k = gidx[g]
+                if fcr[k] >= open_count:
                     continue
-                s2 = state.step(eff_ops[j])
+                s2 = state.step(group_op_list[k])
                 if not m.is_inconsistent(s2):
-                    state, fok = s2, fok | {j}
+                    state = s2
+                    fcr = fcr[:k] + (fcr[k] + 1,) + fcr[k + 1:]
                     if record is not None:
-                        record.append(eff_ops[j])
+                        record.append(group_op_list[k])
                     break
             else:
-                for g, open_count in open_crashed:
-                    k = gidx[g]
-                    if fcr[k] >= open_count:
-                        continue
-                    s2 = state.step(group_op_list[k])
-                    if not m.is_inconsistent(s2):
-                        state = s2
-                        fcr = fcr[:k] + (fcr[k] + 1,) + fcr[k + 1:]
-                        if record is not None:
-                            record.append(group_op_list[k])
-                        break
-                else:
-                    sp.set(completed=False, steps=steps)
-                    return None  # stuck: every greedy move is inconsistent
-        sp.set(completed=True, steps=steps)
+                return None  # stuck: every greedy move is inconsistent
     return True
 
 

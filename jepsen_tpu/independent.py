@@ -189,13 +189,17 @@ def subhistory(key, history: Sequence[Mapping]) -> list[dict]:
 
 class IndependentChecker(Checker):
     """Split the history per key, run the wrapped checker on each, merge
-    validity; per-key results land in ``independent/<key>/``."""
+    validity; per-key results land in ``independent/<key>/``.
+
+    The batch path is four spans, each once per check: the split
+    (``independent.split``), the wrapped checker's ladder call and
+    evidence (``ladder.run``, ``checker.evidence``), and the per-key
+    saves (``independent.save``)."""
 
     def __init__(self, checker: Checker):
         self.checker = checker
 
     def check(self, test, history, opts):
-        keys = history_keys(history)
         opts = dict(opts or {})
 
         def save_key(k, sub, res, from_batch=False):
@@ -223,7 +227,10 @@ class IndependentChecker(Checker):
             # (independent.clj:285-307) as a device batch axis.  A batch
             # failure falls back to the per-key path below so one key's
             # exception can't mask another key's real violation.
-            subs = [h.index(subhistory(k, history)) for k in keys]
+            with obs.span("independent.split", events=len(history)) as sp:
+                keys = history_keys(history)
+                subs = [h.index(subhistory(k, history)) for k in keys]
+                sp.set(keys=len(keys))
             try:
                 batch = self.checker.check_batch(test, subs, opts)
             except faults.CompileError:
@@ -231,11 +238,15 @@ class IndependentChecker(Checker):
             except Exception as e:  # noqa: BLE001 — per-key path isolates it
                 obs.counter("fault.batch.fallback", error=faults.describe(e))
                 batch = None
+        else:
+            keys = history_keys(history)
         if batch is not None:
-            results = {}
-            for k, sub, res in zip(keys, subs, batch):
-                results[k] = res
-                save_key(k, sub, res, from_batch=True)
+            with obs.span("independent.save", keys=len(keys),
+                          stored=store.has_test_dir(test)):
+                results = {}
+                for k, sub, res in zip(keys, subs, batch):
+                    results[k] = res
+                    save_key(k, sub, res, from_batch=True)
         else:
 
             def check_key(k):

@@ -386,11 +386,10 @@ def emit(test: Mapping | None, history, result: dict, *, source: str,
         logger.warning("evidence bundle build failed: %s", e)
         obs.counter("provenance.emit_error", error=type(e).__name__)
         return None
-    test = test or {}
-    if not (test.get("name") and test.get("start-time-str")):
-        return bundle  # no store configured (bare checker unit tests)
     from jepsen_tpu import store
 
+    if not store.has_test_dir(test):
+        return bundle  # no store configured (bare checker unit tests)
     d = store.test_dir(test)
     sub = (opts or {}).get("subdirectory")
     d = d / sub if sub else d

@@ -1457,7 +1457,7 @@ def batch_analysis(
                 "cause": f"device launch failed: {cause}",
             }
         still = []
-        n_true = n_refuted = 0
+        n_true = n_refuted = n_submitted = 0
         peak_max = 0
         n_lossy = 0
         for k in group:
@@ -1511,7 +1511,7 @@ def batch_analysis(
                         confirm_workers, model, list(histories[i]),
                         confirm_max_configs, op_pos,
                     )
-                    obs.counter("confirm.submitted")
+                    n_submitted += 1
                     confirm_futs[i] = (
                         pool, fut, res, time.perf_counter(), op_pos,
                         obs.capture(),
@@ -1527,6 +1527,8 @@ def batch_analysis(
                     "cause": "frontier capacity or closure rounds exhausted",
                     "kernel": stats,
                 }
+        if n_submitted:
+            obs.counter("confirm.submitted", n_submitted)
         for k in still:
             rungs[k] = si + 1
         pending = sorted(rest + still)

@@ -63,6 +63,12 @@ def test_dir(test: Mapping) -> Path:
     return base_dir(test) / str(test["name"]) / str(test["start-time-str"])
 
 
+def has_test_dir(test: Mapping | None) -> bool:
+    """Whether ``test`` carries the store coordinates ``test_dir`` needs
+    (bare checker unit tests and benchmark checks carry none)."""
+    return bool(test and test.get("name") and test.get("start-time-str"))
+
+
 def _jsonable(x: Any):
     if isinstance(x, Mapping):
         return {str(k): _jsonable(v) for k, v in x.items()}

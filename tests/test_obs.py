@@ -1,7 +1,9 @@
 """Telemetry layer tests: the obs API contract (span nesting, JSONL
 round-trip, the disabled no-op fast path), checker attribution, the
-batch-ladder stage table, and the run_test integration (telemetry
-artifacts land in the store dir; disabled runs write nothing)."""
+batch-ladder stage table, the spans of a batch check's front (one each
+a check, whatever the number of keys), and the run_test integration
+(telemetry artifacts land in the store dir; disabled runs write
+nothing)."""
 
 import json
 import pathlib
@@ -535,6 +537,135 @@ def test_batch_analysis_unknowns_observable(tmp_path):
         assert "exact-escalation" in r["cause"]
     rolled = json.loads((tmp_path / "telemetry.json").read_text())
     assert rolled["gauges"]["ladder.unknowns_remaining"] == len(unknowns)
+
+
+# ---------------------------------------------------------------------------
+# The batch check's front (independent.checker over linearizable)
+# ---------------------------------------------------------------------------
+
+#: the spans directly under a batch check's ``checker.check``
+FRONT = ("independent.split", "ladder.run", "checker.evidence",
+         "independent.save")
+#: what the ladder emits once per launch or per stage
+PER_LAUNCH = ("ladder.launch", "ladder.stage", "ladder.compile_cache.hit",
+              "ladder.compile_cache.miss", "wgl.runner_cache.hit",
+              "wgl.runner_cache.miss")
+#: the ladder's dedup probe, once per shape per process
+PER_SHAPE = ("ladder.dedup-probe", "dedup.round")
+
+
+def _keyed_history(n_keys):
+    """``n_keys`` keys of ``_mixed_histories``' shape, one in three
+    corrupted, each key with processes of its own."""
+    from jepsen_tpu import independent
+
+    out = []
+    for k, hh in enumerate(_mixed_histories(n_keys)):
+        out += [{**o, "process": o["process"] + 3 * k,
+                 "value": independent.tuple_(k, o["value"])} for o in hh]
+    return [{**o, "index": i} for i, o in enumerate(out)]
+
+
+def _batch_checker():
+    from jepsen_tpu import independent
+
+    return independent.checker(linearizable(
+        {"model": m.CASRegister(None), "kernel-opts": {"capacity": (64,)}}))
+
+
+@pytest.fixture(scope="module")
+def front_checks(tmp_path_factory):
+    """Recorded batch checks of 8 and of 40 keys: ``{keys: (result,
+    events)}``."""
+    out = {}
+    for n in (8, 40):
+        d = tmp_path_factory.mktemp(f"front{n}")
+        with obs.recording(d) as rec:
+            res = c.check_safe(_batch_checker(), {}, _keyed_history(n))
+        out[n] = (res, list(rec.events))
+    return out
+
+
+def test_front_spans_once_each_under_the_check(front_checks):
+    for n, (res, events) in front_checks.items():
+        assert res["valid?"] is False and len(res["failures"]) == n // 3
+        spans = [e for e in events if e["type"] == "span"]
+        [check] = [e for e in spans if e["name"] == "checker.check"]
+        for name in FRONT:
+            [s] = [e for e in spans if e["name"] == name]
+            assert s["parent"] == "checker.check"
+        by = {e["name"]: e["attrs"] for e in spans if e["name"] in FRONT}
+        assert by["independent.split"] == {
+            "keys": n, "events": sum(len(h) for h in _mixed_histories(n))}
+        assert by["ladder.run"] == {"histories": n}
+        assert by["checker.evidence"] == {"histories": n, "stored": False}
+        assert by["independent.save"] == {"keys": n, "stored": False}
+        assert check["attrs"]["valid"] is False
+
+
+def test_front_spans_cover_the_check(front_checks):
+    for _res, events in front_checks.values():
+        spans = [e for e in events if e["type"] == "span"]
+        [check] = [e for e in spans if e["name"] == "checker.check"]
+        covered, end = 0.0, float("-inf")
+        for a, b in sorted((e["t"], e["t"] + e["dur"]) for e in spans
+                           if e["name"] in FRONT):
+            covered += max(0.0, b - max(a, end))
+            end = max(end, b)
+        assert covered >= 0.9 * check["dur"]
+
+
+def test_front_emits_nothing_per_key(front_checks):
+    """Past the ladder's per-launch, per-stage and per-shape telemetry,
+    a check of 40 keys emits as many spans, counters and events as one
+    of 8 with the same ladder stages; gauges are samples and not
+    counted."""
+    def tally(events):
+        out: dict = {}
+        for e in events:
+            if e["type"] in ("span", "counter", "event") \
+                    and e["name"] not in PER_LAUNCH + PER_SHAPE:
+                out[e["name"]] = out.get(e["name"], 0) + 1
+        return out
+
+    def stages(events):
+        return [(e["attrs"]["engine"], e["attrs"]["capacity"])
+                for e in events if e.get("name") == "ladder.stage"]
+
+    (_, few), (_, many) = front_checks[8], front_checks[40]
+    assert stages(few) == stages(many)
+    assert tally(few) == tally(many)
+
+
+def test_no_greedy_walk_span(front_checks, tmp_path):
+    from jepsen_tpu.checker import wgl_cpu
+
+    for _res, events in front_checks.values():
+        assert not [e for e in events if e.get("name") == "wgl_cpu.greedy_walk"]
+    with obs.recording(tmp_path) as rec:
+        order: list = []
+        hh = _mixed_histories(1)[0]
+        assert wgl_cpu.greedy_walk(m.CASRegister(None), hh, record=order)
+    assert order and [e["type"] for e in rec.events] == ["meta"]
+
+
+def test_front_without_recorder_emits_nothing(front_checks, monkeypatch):
+    """With no recording, the front's call sites take the no-op path: no
+    span object is made and nothing is emitted."""
+    made, emitted = [], []
+
+    class Spy(obs._Span):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(obs, "_Span", Spy)
+    monkeypatch.setattr(obs.Recorder, "emit",
+                        lambda self, ev: emitted.append(ev))
+    assert obs.active() is None
+    res = c.check_safe(_batch_checker(), {}, _keyed_history(8))
+    assert res["valid?"] == front_checks[8][0]["valid?"]
+    assert made == [] and emitted == []
 
 
 # ---------------------------------------------------------------------------
