@@ -22,7 +22,6 @@ from typing import Any, Callable, Mapping, Sequence
 
 from jepsen_tpu import faults, obs
 from jepsen_tpu import generator as gen
-from jepsen_tpu import history as h
 from jepsen_tpu import store
 from jepsen_tpu.checker import Checker, merge_valid, check_safe
 from jepsen_tpu.utils import bounded_pmap
@@ -182,6 +181,44 @@ def subhistory(key, history: Sequence[Mapping]) -> list[dict]:
     return out
 
 
+def split(history: Sequence[Mapping]) -> tuple[list, list[list[dict]], int]:
+    """Every key's subhistory in one pass over the history.
+
+    Returns ``(keys, subs, unkeyed)``.  ``keys`` is
+    ``history_keys(history)``; ``subs[i]`` equals ``subhistory(keys[i],
+    history)`` re-indexed from 0, as ``jepsen_tpu.history.index`` does;
+    ``unkeyed`` counts the ops with no key tag, which every subhistory
+    holds.  Those ops wait in one shared list, and each key's cursor into
+    it catches its subhistory up before the key's next op and at the end:
+    O(N + K·S) work for N ops, K keys and S unkeyed ops, where a
+    ``subhistory`` call per key costs O(K·N)."""
+    shared: list = []  # unkeyed ops, in history order
+    buckets: dict = {}  # key -> [subhistory, cursor into shared]
+
+    def catch_up(b):
+        sub = b[0]
+        for o in shared[b[1]:]:
+            sub.append({**o, "index": len(sub)})
+        b[1] = len(shared)
+
+    for o in history:
+        v = o.get("value")
+        if is_tuple(v):
+            k = tuple_key(v)
+            b = buckets.get(k)
+            if b is None:
+                b = buckets[k] = [[], 0]
+            if b[1] < len(shared):
+                catch_up(b)
+            sub = b[0]
+            sub.append({**o, "value": tuple_value(v), "index": len(sub)})
+        else:
+            shared.append(o)
+    for b in buckets.values():
+        catch_up(b)
+    return list(buckets), [b[0] for b in buckets.values()], len(shared)
+
+
 # ---------------------------------------------------------------------------
 # Checker (independent.clj:266-317)
 # ---------------------------------------------------------------------------
@@ -191,10 +228,11 @@ class IndependentChecker(Checker):
     """Split the history per key, run the wrapped checker on each, merge
     validity; per-key results land in ``independent/<key>/``.
 
-    The batch path is four spans, each once per check: the split
-    (``independent.split``), the wrapped checker's ladder call and
-    evidence (``ladder.run``, ``checker.evidence``), and the per-key
-    saves (``independent.save``)."""
+    Both paths start from one ``split`` of the history (span
+    ``independent.split``).  The batch path is then three more spans,
+    each once per check: the wrapped checker's ladder call and evidence
+    (``ladder.run``, ``checker.evidence``), and the per-key saves
+    (``independent.save``)."""
 
     def __init__(self, checker: Checker):
         self.checker = checker
@@ -219,6 +257,9 @@ class IndependentChecker(Checker):
                 if write is not None:
                     write(test, res, {**opts, "subdirectory": f"independent/{k}"})
 
+        with obs.span("independent.split", events=len(history)) as sp:
+            keys, subs, unkeyed = split(history)
+            sp.set(keys=len(keys), unkeyed=unkeyed)
         batch = None
         if hasattr(self.checker, "check_batch"):
             # Batch-capable checkers (TPU elle / linearizable) take every
@@ -227,10 +268,6 @@ class IndependentChecker(Checker):
             # (independent.clj:285-307) as a device batch axis.  A batch
             # failure falls back to the per-key path below so one key's
             # exception can't mask another key's real violation.
-            with obs.span("independent.split", events=len(history)) as sp:
-                keys = history_keys(history)
-                subs = [h.index(subhistory(k, history)) for k in keys]
-                sp.set(keys=len(keys))
             try:
                 batch = self.checker.check_batch(test, subs, opts)
             except faults.CompileError:
@@ -238,8 +275,6 @@ class IndependentChecker(Checker):
             except Exception as e:  # noqa: BLE001 — per-key path isolates it
                 obs.counter("fault.batch.fallback", error=faults.describe(e))
                 batch = None
-        else:
-            keys = history_keys(history)
         if batch is not None:
             with obs.span("independent.save", keys=len(keys),
                           stored=store.has_test_dir(test)):
@@ -249,14 +284,14 @@ class IndependentChecker(Checker):
                     save_key(k, sub, res, from_batch=True)
         else:
 
-            def check_key(k):
-                sub = h.index(subhistory(k, history))
+            def check_key(k_sub):
+                k, sub = k_sub
                 sub_opts = {**opts, "subdirectory": f"independent/{k}"}
                 res = check_safe(self.checker, test, sub, sub_opts)
                 save_key(k, sub, res)
                 return k, res
 
-            results = dict(bounded_pmap(check_key, keys))
+            results = dict(bounded_pmap(check_key, list(zip(keys, subs))))
         valid = merge_valid([r.get("valid?") for r in results.values()] or [True])
         failures = [k for k, r in results.items() if r.get("valid?") is not True]
         return {

@@ -596,11 +596,28 @@ def test_front_spans_once_each_under_the_check(front_checks):
             assert s["parent"] == "checker.check"
         by = {e["name"]: e["attrs"] for e in spans if e["name"] in FRONT}
         assert by["independent.split"] == {
-            "keys": n, "events": sum(len(h) for h in _mixed_histories(n))}
+            "keys": n, "events": sum(len(h) for h in _mixed_histories(n)),
+            "unkeyed": 0}
         assert by["ladder.run"] == {"histories": n}
         assert by["checker.evidence"] == {"histories": n, "stored": False}
         assert by["independent.save"] == {"keys": n, "stored": False}
         assert check["attrs"]["valid"] is False
+
+
+def test_front_split_counts_unkeyed_ops(front_checks, tmp_path):
+    """Nemesis ops land in every key's subhistory; the split span counts
+    them once a check, and the verdicts stay those of the keyed ops."""
+    hist = _keyed_history(8)
+    nemesis = {"type": "info", "f": "kill", "value": None,
+               "process": "nemesis"}
+    hist = hist[:1] + [nemesis] + hist[1:len(hist) // 2] + [nemesis] \
+        + hist[len(hist) // 2:]
+    hist = [{**o, "index": i} for i, o in enumerate(hist)]
+    with obs.recording(tmp_path) as rec:
+        res = c.check_safe(_batch_checker(), {}, hist)
+    [split] = [e for e in rec.events if e.get("name") == "independent.split"]
+    assert split["attrs"] == {"keys": 8, "events": len(hist), "unkeyed": 2}
+    assert res["failures"] == front_checks[8][0]["failures"]
 
 
 def test_front_spans_cover_the_check(front_checks):

@@ -98,6 +98,96 @@ def test_independent_checker_merges_validity():
     assert res["results"]["a"]["valid?"] is True
 
 
+def _unkeyed(rng):
+    """A nemesis-style op: no key tag, sometimes no ``value`` at all."""
+    return rng.choice([
+        {"type": "info", "f": "start", "value": None, "process": "nemesis"},
+        {"type": "info", "f": "kill", "value": ["n1", "n2", "n3"],
+         "process": "nemesis"},
+        {"type": "info", "f": "stop", "process": "nemesis"},
+        {"type": "invoke", "f": "read", "value": 3, "process": 9},
+    ])
+
+
+def _random_keyed_history(seed):
+    """Seeded keyed history over int and str keys, with unkeyed ops before
+    the first keyed op, between keyed ops and after the last one."""
+    rng = random.Random(seed)
+    keys = rng.sample([0, 1, 2, 7, "a", "b", "zz"], rng.randint(1, 5))
+    out = [_unkeyed(rng) for _ in range(rng.randint(0, 3))]
+    for _ in range(rng.randint(5, 80)):
+        if rng.random() < 0.2:
+            out.append(_unkeyed(rng))
+            continue
+        k, p = rng.choice(keys), rng.randint(0, 4)
+        out.append({"type": "invoke", "f": "write", "process": p,
+                    "value": independent.tuple_(k, rng.randint(0, 4))})
+        out.append({"type": rng.choice(["ok", "info"]), "f": "write",
+                    "process": p,
+                    "value": independent.tuple_(k, rng.randint(0, 4))})
+    out += [_unkeyed(rng) for _ in range(rng.randint(0, 3))]
+    if seed % 2:  # half the histories come indexed, as run_test leaves them
+        out = h.index(out)
+    return out
+
+
+_SPLIT_CASES = {
+    **{f"random-{s}": _random_keyed_history(s) for s in range(8)},
+    "empty": [],
+    "no-keyed-ops": [_unkeyed(random.Random(i)) for i in range(6)],
+    "key-once": [
+        {"type": "info", "f": "start", "process": "nemesis"},
+        invoke("read", independent.tuple_("x", None), 0, index=5),
+        invoke("read", independent.tuple_(3, None), 1),
+        {"type": "info", "f": "stop", "value": None, "process": "nemesis"},
+        ok("read", independent.tuple_(3, 2), 1),
+        {"type": "info", "f": "start", "process": "nemesis"},
+    ],
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SPLIT_CASES))
+def test_split_matches_per_key_subhistory(name):
+    hist = _SPLIT_CASES[name]
+    keys, subs, unkeyed = independent.split(hist)
+    oracle = independent.history_keys(hist)
+    assert keys == oracle
+    assert subs == [h.index(independent.subhistory(k, hist)) for k in oracle]
+    assert unkeyed == sum(not independent.is_tuple(o.get("value")) for o in hist)
+
+
+def test_independent_fallback_matches_batch():
+    """A checker without ``check_batch`` takes the per-key path (as a
+    batch that raises does) over the same subhistories, nemesis ops
+    included, with the same results as the batch path."""
+    from jepsen_tpu.checker import Checker
+
+    class Seen(Checker):
+        def check(self, test, history, opts):
+            vals = [o.get("value") for o in history if o["type"] == "ok"]
+            return {"valid?": all(v != 2 for v in vals),
+                    "history": [dict(o) for o in history]}
+
+    class Batch(Seen):
+        def check_batch(self, test, histories, opts):
+            return [self.check(test, hh, opts) for hh in histories]
+
+    hist = pairs(
+        ("read", independent.tuple_("a", None), independent.tuple_("a", 1), 0),
+        ("read", independent.tuple_("b", None), independent.tuple_("b", 2), 1),
+    )
+    hist.insert(2, {"type": "info", "f": "kill", "value": None,
+                    "process": "nemesis"})
+    batch = independent.checker(Batch()).check({"name": "t"}, hist, {})
+    per_key = independent.checker(Seen()).check({"name": "t"}, hist, {})
+    assert per_key == batch
+    assert batch["failures"] == ["b"]
+    for k in ("a", "b"):
+        assert batch["results"][k]["history"] == h.index(
+            independent.subhistory(k, hist))
+        assert "kill" in [o["f"] for o in batch["results"][k]["history"]]
+
+
 # ---------------------------------------------------------------------------
 # bank
 # ---------------------------------------------------------------------------
