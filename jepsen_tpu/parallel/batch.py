@@ -191,7 +191,11 @@ def _stays_pending(valid, fat, lossy) -> bool:
 
 def _resolve_confirmation(res: dict, cpu_res: dict) -> dict:
     """Fold an exact-sweep confirmation verdict into the device result
-    (shared by the worker and device confirm paths)."""
+    (shared by the worker and device confirm paths).  Where the device
+    refuted nothing (a lane the ladder left undecided, swept whole),
+    the sweep's verdict stands."""
+    if res.get("valid?") is not False:
+        return cpu_res
     if cpu_res["valid?"] is False:
         return {**res, "confirmed?": True}
     if cpu_res["valid?"] is True:
@@ -408,8 +412,11 @@ def batch_analysis(
     relied on the implicit exact stage to resolve capacity-bound lanes
     may see more "unknown"s and should pass ``exact_escalation``
     explicitly.  Remaining unknowns fall back to the CPU config-set
-    sweep when ``cpu_fallback``.  Returns one knossos-shaped result per
-    history, in order.
+    sweep when ``cpu_fallback``: a history the last rung leaves lossy is
+    swept whole in the confirmation workers as soon as it leaves the
+    ladder, where refutations are confirmed there (and no mesh rescue
+    runs); the rest in this process after the ladder.  Returns one
+    knossos-shaped result per history, in order.
 
     ``dedup_backend`` selects the per-round frontier dedup backend for
     every rung — "sort" (multi-key hash sort) or "bucket" (packed radix
@@ -955,14 +962,16 @@ def batch_analysis(
             if i in pack_of:
                 resumes[pack_of[i]] = fr
         for i, info in restored["confirms"].items():
+            # no failure barrier: an undecided lane swept whole
+            op_pos = None if info["op_pos"] is None else int(info["op_pos"])
             pool, fut = _submit_confirmation(
                 confirm_workers, model, list(histories[i]),
-                confirm_max_configs, int(info["op_pos"]),
+                confirm_max_configs, op_pos,
             )
             obs.counter("confirm.submitted")
             confirm_futs[i] = (
-                pool, fut, info["res"], time.perf_counter(),
-                int(info["op_pos"]), obs.capture(),
+                pool, fut, info["res"], time.perf_counter(), op_pos,
+                obs.capture(),
             )
             results[i] = info["res"]
         for e in restored["device_confirms"]:
@@ -1113,6 +1122,17 @@ def batch_analysis(
     #: it every stage would pay the fault each time).
     budget_scale = 1.0
     exhausted: list[int] = []  # packs that ran out of rungs unresolved
+    #: Where the mesh rescue will not run and refutations are confirmed
+    #: by worker sweeps, a pack that leaves the last rung undecided (its
+    #: frontier outgrew the widest capacity) goes whole to the same
+    #: exact sweep in the confirmation workers at once, concurrent with
+    #: the rest of the ladder and the other confirmations, instead of to
+    #: the serial in-process CPU fallback after the ladder.
+    mesh_rescue = (dedup == "pallas" and mesh is not None
+                   and mesh.devices.size > 1)
+    sweep_undecided = (cpu_fallback and confirm_refutations is True
+                       and not mesh_rescue)
+    n_swept = 0  # undecided packs handed to the worker sweeps
     #: Lowest-rung-first selection + rung-0 joiner admission could defer
     #: an escalated member forever under a steady arrival stream; a
     #: member skipped for more than _STARVE_SECONDS gets its rung served
@@ -1128,8 +1148,22 @@ def batch_analysis(
         # unknowns: the exact-confirm/CPU-fallback tail decides them).
         past = [k for k in pending if rungs[k] >= len(stages)]
         if past:
-            exhausted.extend(past)
             pending = [k for k in pending if rungs[k] < len(stages)]
+            if not sweep_undecided or (deadline is not None
+                                       and deadline.expired()):
+                exhausted.extend(past)
+                past = []
+        for k in past:
+            # no failure barrier to bound it: the sweep runs whole
+            i = idxs[k]
+            pool, fut = _submit_confirmation(
+                confirm_workers, model, list(histories[i]),
+                confirm_max_configs, None,
+            )
+            confirm_futs[i] = (pool, fut, results[i], time.perf_counter(),
+                               None, obs.capture())
+            _pv(i, "ladder.exhausted", route="worker-sweep")
+            n_swept += 1
         if not pending:
             if admission is not None and confirm_futs:
                 # Linger while worker confirmations are in flight: keep
@@ -1544,8 +1578,7 @@ def batch_analysis(
             min(rungs[k] for k in pending) if pending else si + 1
         )
 
-    if (exhausted and dedup == "pallas" and mesh is not None
-            and mesh.devices.size > 1):
+    if exhausted and mesh_rescue:
         # Mesh rescue (round 12): before the ladder admits defeat, the
         # exhausted lanes get ONE run of the mesh-SPANNING fused stage —
         # the whole mesh as a single frontier at devices x the top rung
@@ -1934,7 +1967,7 @@ def batch_analysis(
     if confirm_futs:
         obs.span_event(
             "ladder.confirm.drain", time.perf_counter() - t_drain,
-            confirmations=len(confirm_futs),
+            confirmations=len(confirm_futs), undecided=n_swept,
         )
 
     if packs and batch_caps and obs.active() is not None:
