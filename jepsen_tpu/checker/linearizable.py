@@ -256,14 +256,18 @@ class Linearizable(Checker):
         evidence bundle, witness walk included.  Rung admission can grow
         the result list past the input histories; bundles go to the
         caller-supplied ones (joiner verdicts are the admission hook's to
-        bundle — the serving layer does so per request)."""
+        bundle — the serving layer does so per request).  The span's
+        ``fingerprint_s``, ``witness_s`` and ``digest_s`` sum those parts
+        of the bundles' builds."""
+        times = dict.fromkeys(_prov.BUILD_TIMES, 0.0)
         with obs.span("checker.evidence", histories=len(histories),
-                      stored=store.has_test_dir(test)):
+                      stored=store.has_test_dir(test)) as sp:
             outs = [self._truncate(r) for r in results]
             for hh, out in zip(histories, outs):
                 _prov.emit(test, hh, out, source="check_batch",
                            model=self.model, checker="linearizable",
-                           opts=opts)
+                           opts=opts, times=times)
+            sp.set(**{k: round(v, 6) for k, v in times.items()})
         return outs
 
 

@@ -39,11 +39,13 @@ from __future__ import annotations
 import hashlib
 import json
 import logging
+import time
 import uuid
 from pathlib import Path
 from typing import Mapping, Sequence
 
 from jepsen_tpu import obs
+from jepsen_tpu import store as _store
 
 logger = logging.getLogger(__name__)
 
@@ -238,7 +240,10 @@ def _extract_witness(model, history, result: Mapping) -> dict | None:
 
 
 def _strip(x):
-    if isinstance(x, Mapping):
+    t = type(x)
+    if t in _store._JSON_SCALARS:
+        return x  # before the ``Mapping`` ABC check, which costs more
+    if t is dict or isinstance(x, Mapping):
         return {
             str(k): _strip(v) for k, v in x.items()
             if str(k) not in _DIGEST_STRIP
@@ -275,18 +280,31 @@ def bundle_digest(payload: Mapping) -> str:
     return hashlib.sha256(durable.canonical_bytes(core)).hexdigest()
 
 
+#: the parts of a bundle's build that ``times`` accumulates, in seconds:
+#: the history fingerprint, the witness walk, the digest
+BUILD_TIMES = ("fingerprint_s", "witness_s", "digest_s")
+
+
 def build_bundle(*, history, result: Mapping, source: str,
                  model=None, checker: str | None = None,
                  trace_id=None, config: Mapping | None = None,
                  extra_path: Sequence[Mapping] | None = None,
-                 bundle_id: str | None = None) -> dict:
+                 bundle_id: str | None = None,
+                 times: dict | None = None) -> dict:
     """Assemble one evidence-bundle payload (no I/O).
 
     ``result`` may carry a ``provenance`` block from :func:`attach`;
     ``extra_path`` entries (the serving layer's admission/fastpath/
     bisect events) are prepended before it.  The returned payload's
-    ``digest`` field is the stability-core digest.
+    ``digest`` field is the stability-core digest.  ``times``, a dict
+    with the keys of :data:`BUILD_TIMES`, has this build's seconds of
+    each part added to it.
     """
+    t0 = time.perf_counter()
+    fp = history_fingerprint(history) if history is not None else None
+    t1 = time.perf_counter()
+    witness = _extract_witness(model, history, result)
+    t2 = time.perf_counter()
     prov = result.get("provenance") or {}
     path = [dict(e) for e in (extra_path or ())]
     path += [dict(e) for e in prov.get("path", ())]
@@ -299,21 +317,25 @@ def build_bundle(*, history, result: Mapping, source: str,
         "source": str(source),
         "model": getattr(model, "name", None) if model is not None else None,
         "checker": checker,
-        "history_fingerprint": history_fingerprint(history)
-        if history is not None else None,
+        "history_fingerprint": fp,
         "history_ops": len(history) if history is not None else None,
         "verdict": verdict_str(v),
         "cause": result.get("cause"),
         "decision_path": path,
         "engine": engine,
         "config": cfg,
-        "witness": _extract_witness(model, history, result),
+        "witness": witness,
         "machine": machine_fingerprint(),
         "trace_id": trace_id,
     }
     if history is not None and len(history) <= MAX_EMBED_OPS:
         payload["history"] = [dict(op) for op in history]
+    t3 = time.perf_counter()
     payload["digest"] = bundle_digest(payload)
+    if times is not None:
+        times["fingerprint_s"] += t1 - t0
+        times["witness_s"] += t2 - t1
+        times["digest_s"] += time.perf_counter() - t3
     return payload
 
 
@@ -370,17 +392,18 @@ def iter_bundles(run_dir):
 def emit(test: Mapping | None, history, result: dict, *, source: str,
          model=None, checker: str | None = None,
          config: Mapping | None = None, opts: Mapping | None = None,
-         trace_id=None) -> dict | None:
+         trace_id=None, times: dict | None = None) -> dict | None:
     """Checker-level emission: build a bundle for ``result`` and write
     it under the run's store dir (``<test-dir>/evidence/<id>.json``).
     Mirrors ``_render_failure``'s guard — a bare unit-test checker with
     no store coordinates records nothing (but the in-memory provenance
     stays on the result).  Sets ``result["evidence"] = {id, digest,
-    path}`` on success; never raises."""
+    path}`` on success; never raises.  ``times`` is
+    :func:`build_bundle`'s."""
     try:
         bundle = build_bundle(
             history=history, result=result, source=source, model=model,
-            checker=checker, config=config, trace_id=trace_id,
+            checker=checker, config=config, trace_id=trace_id, times=times,
         )
     except Exception as e:  # noqa: BLE001 — provenance never loses verdicts
         logger.warning("evidence bundle build failed: %s", e)

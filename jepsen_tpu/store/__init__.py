@@ -69,7 +69,23 @@ def has_test_dir(test: Mapping | None) -> bool:
     return bool(test and test.get("name") and test.get("start-time-str"))
 
 
+#: the exact types JSON holds as they are.  Subclasses (``IntEnum``, a
+#: ``str`` subclass) are not here: they take ``_jsonable``'s general
+#: chain, whose output for them is the contract.
+_JSON_SCALARS = frozenset({str, int, float, bool, type(None)})
+
+
 def _jsonable(x: Any):
+    # exact-type dispatch first: ``isinstance`` against the ``Mapping``
+    # ABC costs more than the rest of the encoding for plain values, and
+    # every store write, envelope CRC and history fingerprint comes here
+    t = type(x)
+    if t in _JSON_SCALARS:
+        return x
+    if t is dict:
+        return {str(k): _jsonable(v) for k, v in x.items()}
+    if t is list or t is tuple:
+        return [_jsonable(v) for v in x]
     if isinstance(x, Mapping):
         return {str(k): _jsonable(v) for k, v in x.items()}
     if isinstance(x, (list, tuple, set, frozenset)):

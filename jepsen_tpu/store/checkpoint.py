@@ -125,6 +125,11 @@ def exists(d) -> bool:
     return json_path(d).exists()
 
 
+#: the encoder ``json.dumps(x, separators=(",", ":"), default=str)``
+#: builds on every call, built once
+_OP_ENCODER = json.JSONEncoder(separators=(",", ":"), default=str)
+
+
 def fingerprint(histories: Sequence[Sequence[Mapping]]) -> str:
     """A stable identity for the checked inputs: sha256 over every op's
     (type, process, f, value) in order, per history.
@@ -137,6 +142,7 @@ def fingerprint(histories: Sequence[Sequence[Mapping]]) -> str:
     same stored run), and a spurious mismatch merely means a fresh run,
     never a wrong resume."""
     h = hashlib.sha256()
+    encode, jsonable = _OP_ENCODER.encode, _store._jsonable
     for hist in histories:
         h.update(b"\x00")
         cols = getattr(hist, "cols", None)
@@ -152,19 +158,13 @@ def fingerprint(histories: Sequence[Sequence[Mapping]]) -> str:
                                default=str).encode()
                 )
             continue
-        for o in hist:
-            h.update(
-                json.dumps(
-                    [
-                        _store._jsonable(o.get("type")),
-                        _store._jsonable(o.get("process")),
-                        _store._jsonable(o.get("f")),
-                        _store._jsonable(o.get("value")),
-                    ],
-                    separators=(",", ":"),
-                    default=str,
-                ).encode()
-            )
+        # one update per history: sha256 streams, so the digest is that
+        # of one update per op
+        h.update("".join([
+            encode([jsonable(o.get("type")), jsonable(o.get("process")),
+                    jsonable(o.get("f")), jsonable(o.get("value"))])
+            for o in hist
+        ]).encode())
     return h.hexdigest()
 
 

@@ -123,16 +123,20 @@ class ReadResult:
 # ---------------------------------------------------------------------------
 
 
+#: ``json.dumps(x, sort_keys=True, separators=(",", ":"), default=str)``
+#: without a fresh encoder per call (the same bytes: ``dumps`` builds
+#: this very encoder)
+_CANONICAL = json.JSONEncoder(sort_keys=True, separators=(",", ":"),
+                              default=str)
+
+
 def canonical_bytes(payload) -> bytes:
     """The byte string the payload CRC is computed over: sorted-key,
     separator-free canonical JSON (stable across dict insertion order
     and whitespace)."""
     from jepsen_tpu import store as _store
 
-    return json.dumps(
-        _store._jsonable(payload), sort_keys=True, separators=(",", ":"),
-        default=str,
-    ).encode()
+    return _CANONICAL.encode(_store._jsonable(payload)).encode()
 
 
 def payload_crc(payload) -> int:

@@ -599,7 +599,13 @@ def test_front_spans_once_each_under_the_check(front_checks):
             "keys": n, "events": sum(len(h) for h in _mixed_histories(n)),
             "unkeyed": 0}
         assert by["ladder.run"] == {"histories": n}
-        assert by["checker.evidence"] == {"histories": n, "stored": False}
+        # the evidence span's parts: seconds summed over its keys
+        [ev] = [e for e in spans if e["name"] == "checker.evidence"]
+        parts = ("fingerprint_s", "witness_s", "digest_s")
+        assert set(ev["attrs"]) == {"histories", "stored", *parts}
+        assert (ev["attrs"]["histories"], ev["attrs"]["stored"]) == (n, False)
+        assert all(ev["attrs"][k] >= 0 for k in parts)
+        assert sum(ev["attrs"][k] for k in parts) <= ev["dur"]
         assert by["independent.save"] == {"keys": n, "stored": False}
         assert check["attrs"]["valid"] is False
 
