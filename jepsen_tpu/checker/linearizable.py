@@ -229,7 +229,7 @@ class Linearizable(Checker):
         batch_kw = {
             k: v
             for k, v in self.kernel_opts.items()
-            if k in ("capacity", "rounds", "mesh", "exact_escalation", "engine")
+            if k in ("capacity", "rounds", "mesh", "exact_escalation")
         }
         # Fault-tolerance keys ride the CHECKER OPTS (core.analyze fills
         # them from the test map / CLI): the ladder checkpoints into the

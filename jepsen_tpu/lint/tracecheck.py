@@ -29,7 +29,7 @@ onto their parameters — then flags:
     without an explicit ``dtype``: the weak-type default shifts with
     operand promotion, and a shifted dtype is a new compile bucket;
   * ``trace-raw-geometry`` — a function calling a jit-runner factory
-    (``batched_runner`` & co.) without deriving its shapes from the
+    (``async_runner`` & co.) without deriving its shapes from the
     padded-geometry helpers (``bucket_geometry``/``padded_batch``/
     ``pad_*``): every distinct raw shape is a hidden compile bucket.
 
@@ -80,7 +80,7 @@ _COMBINATORS = {
 #: jit-runner factories (compiled-launch entry points) for the
 #: raw-geometry audit ...
 _RUNNER_FACTORIES = {
-    "batched_runner", "exact_batched_runner", "async_runner",
+    "exact_batched_runner", "async_runner",
     "greedy_runner", "lane_shard", "_sharded_runner",
 }
 

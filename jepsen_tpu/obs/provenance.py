@@ -638,16 +638,20 @@ def replay_bundle(bundle, *, deadline_zero_on_deadline_path: bool = True) -> dic
             if model is None:
                 errors.append("no model recorded; cannot replay")
                 return out
-            from jepsen_tpu.parallel import batch_analysis
+            from jepsen_tpu.parallel.batch import batch_analysis, removed_path
 
+            removed = removed_path(cfg)
+            if removed is not None:
+                errors.append(f"bundle pins a removed ladder path: {removed}")
+                return out
             kw = {}
             if cfg.get("capacity"):
                 kw["capacity"] = tuple(int(c) for c in cfg["capacity"])
             if cfg.get("exact_escalation") is not None:
                 kw["exact_escalation"] = tuple(
                     int(c) for c in cfg["exact_escalation"])
-            for k in ("rounds", "engine", "greedy_first", "carry_frontier",
-                      "confirm_refutations", "frontier_budget_mb"):
+            for k in ("rounds", "greedy_first", "confirm_refutations",
+                      "frontier_budget_mb"):
                 if cfg.get(k) is not None:
                     kw[k] = cfg[k]
             if engine.get("dedup_backend"):

@@ -321,7 +321,7 @@ def stage_rollup(summary: Mapping | None) -> tuple[dict, dict]:
 
     ``stages`` maps span names to total seconds: one entry per ladder
     rung (``ladder[<stage>] <engine>@<capacity>``) plus every rolled-up
-    span (phases, confirm device/drain, serve.batch, checker.check, ...)
+    span (phases, confirm drain, serve.batch, checker.check, ...)
     — the table ``diff_stage_tables`` attributes regressions over.
     ``metrics`` carries the scalar side channels worth trending on their
     own: serve occupancy and latency means, confirm-queue latency, dedup

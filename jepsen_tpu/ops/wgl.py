@@ -29,7 +29,7 @@ witness — ``True`` is always sound, truncated or not.  ``False`` requires
 that no capacity/round loss occurred anywhere (``lossy`` flag); on the
 single-history path (chunked_analysis) kills are content-decided
 (frontier_update / exact_prune), so its refutations are exact, while the
-batched fast engines dedup by 64-bit row hash and their refutations are
+batched async engine dedups by 64-bit row hash and its refutations are
 therefore CONFIRMED on the exact CPU sweep before being reported
 (jepsen_tpu.parallel.batch_analysis overlaps the confirmation with the
 remaining device stages, so it is sound and nearly free in wall clock).
@@ -698,49 +698,26 @@ def chunk_runner(step, F: int, R: int, P: int, G: int, W: int, fast: bool,
 #: 8x capacity headroom per row, ample for real closures).
 _MAX_SLICES = 64
 
-#: (step, F, R, P, G, W, fast, dedup) -> jitted vmapped runner over a
+#: (step, F, R, P, G, W, dedup) -> jitted vmapped exact runner over a
 #: leading batch axis.
-_BATCH_RUNNERS: dict = {}
-
-
-def batched_runner(step, F: int, R: int, P: int, G: int, W: int,
-                   dedup: str = "sort"):
-    """A jit(vmap(_run_core)) specialised to the given static shapes: checks
-    a stack of same-shape packed histories in one device program (BASELINE
-    config 4: hundreds of recorded histories vmapped across a slice).
-    slot tables are shape-derived and shared; everything else is batched.
-
-    Uses the fast hash-lane frontier update: under vmap, multi-key sorts
-    and full-table gathers dominate wall clock; stragglers that overflow
-    its capacity escalate to the exact path or the CPU oracle
-    (jepsen_tpu.parallel.batch).  ``dedup`` selects the per-round dedup
-    backend (jepsen_tpu.ops.hashing, "sort"|"bucket")."""
-    key = (step, F, R, P, G, W, True, dedup)
-    _cache_counter(_BATCH_RUNNERS, key, "sync")
-    if key not in _BATCH_RUNNERS:
-        core = functools.partial(_run_core, step, F, R, P, G, W, True, dedup=dedup)
-        axes = (0,) * 14 + (None, None)
-        _BATCH_RUNNERS[key] = faults.AheadOfTime(jax.jit(jax.vmap(core, in_axes=axes)))
-    return _BATCH_RUNNERS[key]
+_EXACT_RUNNERS: dict = {}
 
 
 def exact_batched_runner(step, F: int, R: int, P: int, G: int, W: int,
                          dedup: str = "sort"):
     """jit(vmap(_run_core)) with the EXACT frontier update (sorted windowed
     (state, fok) compares + two-stage domination — kills are content
-    compares, never hash-identity, under BOTH dedup backends).  One launch
-    replaces the former Python
-    loop of per-history exact escalations: every straggler and every
-    fast-engine refutation confirms in the same vmapped program, so the
-    escalation stage costs one launch instead of ~60% of bench wall clock
-    (round-2 profile)."""
-    key = (step, F, R, P, G, W, False, dedup)
-    _cache_counter(_BATCH_RUNNERS, key, "exact")
-    if key not in _BATCH_RUNNERS:
+    compares, never hash-identity, under BOTH dedup backends).  Every
+    straggler of an exact ladder rung runs in the same vmapped program,
+    so the rung costs one launch instead of a Python loop of per-history
+    exact escalations (~60% of bench wall clock in the round-2 profile)."""
+    key = (step, F, R, P, G, W, dedup)
+    _cache_counter(_EXACT_RUNNERS, key, "exact")
+    if key not in _EXACT_RUNNERS:
         core = functools.partial(_run_core, step, F, R, P, G, W, False, dedup=dedup)
         axes = (0,) * 14 + (None, None)
-        _BATCH_RUNNERS[key] = faults.AheadOfTime(jax.jit(jax.vmap(core, in_axes=axes)))
-    return _BATCH_RUNNERS[key]
+        _EXACT_RUNNERS[key] = faults.AheadOfTime(jax.jit(jax.vmap(core, in_axes=axes)))
+    return _EXACT_RUNNERS[key]
 
 
 # ---------------------------------------------------------------------------
@@ -782,14 +759,14 @@ def _cache_counter(cache: dict, key, kind: str) -> None:
 
 
 def evict_runner_caches() -> int:
-    """Drop every cached jitted runner (batched / async / greedy / chunk):
+    """Drop every cached jitted runner (exact / async / greedy / chunk):
     releasing the references lets the backend free the executables and
     their device buffers — the process-level spill lever the OOM policy
     pulls BEFORE halving work (``jepsen_tpu.faults.try_oom_spill``; the
     default spiller in ``parallel.batch`` calls this only on non-CPU
     backends, where allocator pressure is real).  The cost is
     recompiles later, never correctness.  Returns entries evicted."""
-    caches = (_BATCH_RUNNERS, _ASYNC_RUNNERS, _GREEDY_RUNNERS, _CHUNK_RUNNERS)
+    caches = (_EXACT_RUNNERS, _ASYNC_RUNNERS, _GREEDY_RUNNERS, _CHUNK_RUNNERS)
     n = sum(len(c) for c in caches)
     for c in caches:
         c.clear()

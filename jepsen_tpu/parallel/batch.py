@@ -20,7 +20,7 @@ from concurrent.futures import ProcessPoolExecutor
 from concurrent.futures import TimeoutError as FutureTimeout
 from concurrent.futures.process import BrokenProcessPool
 from pathlib import Path
-from typing import Sequence
+from typing import Mapping, Sequence
 
 import numpy as np
 
@@ -66,12 +66,14 @@ _PROBED_DEDUP_SHAPES: set[tuple] = set()
 #: loop's budget comment — re-measure the true threshold on-chip).
 _EXACT_LANE_BUDGET = 16 * 1024
 
-#: fast-engine frontier rows per launch; the carried-frontier variant
-#: halves it because the resume snapshot doubles the async kernel's
-#: resident per-lane footprint (tests shrink these to force multi-chunk
-#: stages on small workloads).
-_FAST_LANE_BUDGET = 64 * 1024
+#: async-rung frontier rows per launch: half the greedy stage's, because
+#: the carried resume snapshot doubles the async kernel's resident
+#: per-lane footprint (tests shrink it to force multi-chunk stages on
+#: small workloads).
 _CARRY_LANE_BUDGET = 32 * 1024
+
+#: greedy-stage lanes per launch (capacity 1: one row per lane).
+_GREEDY_LANE_BUDGET = 64 * 1024
 
 #: the padded-geometry bucket tables every batched launch quantizes to
 #: (P = slots, G = groups); bucket_geometry is the single source the
@@ -189,9 +191,24 @@ def _stays_pending(valid, fat, lossy) -> bool:
     return True
 
 
+def removed_path(config: Mapping) -> str | None:
+    """The removed ladder path (the "sync" engine, device-side
+    confirmation, scratch-restart rungs) a recorded ladder ``config``
+    pins, or None.  Such a checkpoint or evidence bundle is refused,
+    never re-run under the remaining ladder as the same check; a key
+    the record lacks means the remaining path."""
+    if config.get("engine", "async") != "async":
+        return f"engine={config['engine']!r}"
+    if config.get("confirm_refutations", True) not in (True, False):
+        return f"confirm_refutations={config['confirm_refutations']!r}"
+    if config.get("carry_frontier", True) is not True:
+        return f"carry_frontier={config['carry_frontier']!r}"
+    return None
+
+
 def _resolve_confirmation(res: dict, cpu_res: dict) -> dict:
     """Fold an exact-sweep confirmation verdict into the device result
-    (shared by the worker and device confirm paths).  Where the device
+    (shared by the worker confirmations and the mesh rescue).  Where the device
     refuted nothing (a lane the ladder left undecided, swept whole),
     the sweep's verdict stands."""
     if res.get("valid?") is not False:
@@ -345,11 +362,9 @@ def batch_analysis(
     mesh: Mesh | None = None,
     cpu_fallback: bool = True,
     exact_escalation: Sequence[int] | None = None,
-    engine: str = "async",
     confirm_refutations: bool = True,
     confirm_workers: int | None = None,
     confirm_max_configs: int = 2_000_000,
-    carry_frontier: bool = True,
     greedy_first: bool = True,
     dedup_backend: str | None = None,
     checkpoint_dir: str | Path | None = None,
@@ -360,69 +375,41 @@ def batch_analysis(
 ) -> list[dict]:
     """Check many histories against one model in batched kernel launches.
 
-    ``capacity`` lists the BATCHED (fast-kernel) capacity ladder: each
-    stage re-batches only the still-unknown histories, padded to a power
-    of two so compiles are reused.  ``engine`` picks the batched kernel:
-    "async" (lane-asynchronous barrier stepping — lanes pay their own
-    closure depth; the default: with candidate-order truncation it
-    matches the sync engine's verdict quality and runs the full ladder
-    ~15% faster) or "sync" (the barrier-scan kernel).  ``rounds`` bounds per-barrier
-    closure depth on the "sync" engine and the exact escalation stages;
-    the async engine's closure budget is its tick budget
-    (wgl.async_ticks).
-
-    ``greedy_first`` (default) prepends a capacity-1 greedy witness-walk
-    stage (wgl.greedy_runner): most VALID lanes resolve there for the
-    cost of one buffer-free scan, so the beam ladder only pays for the
-    contested lanes.  The walk never refutes, so soundness is untouched.
+    The ladder: with ``greedy_first`` (default) a capacity-1 greedy
+    witness walk (wgl.greedy_runner), where most VALID lanes resolve
+    for one buffer-free scan and nothing is refuted; then the async
+    rungs of ``capacity`` (wgl.async_runner, lanes step barriers
+    asynchronously within a tick budget, wgl.async_ticks); then the
+    exact rungs of ``exact_escalation`` (frontier_update, ~10x slower
+    per lane but content-exact, closure depth bounded by ``rounds``;
+    ``None`` means none).  Each rung re-batches only the still-undecided
+    histories, padded to a power of two so compiles are reused, and
+    wide rungs sub-batch.  An async rung RESUMES each straggler from the
+    exact pre-loss frontier the previous async rung saved at its failure
+    barrier: the verified prefix is never re-paid, and the tick budget
+    shrinks to the max REMAINING barriers.
 
     ``True`` verdicts are sound from every stage (a surviving frontier is
-    a constructive witness).  The fast engines dedup by 64-bit row hash,
-    so their refutations are PROVISIONAL: with ``confirm_refutations``
-    (the default, honoring the "never an unconfirmed False" contract)
-    each one is confirmed by the exact CPU config-set sweep running in
-    worker processes CONCURRENTLY with the remaining device stages — by
-    the time the ladder drains, the confirmations have usually finished,
-    so soundness costs almost no wall clock.  A sweep that exceeds
-    ``confirm_max_configs`` leaves the verdict "unknown" (never a wrong
-    False); a sweep that disagrees (the ~1e-13 collision case) wins.
-    ``confirm_refutations="device"`` confirms on the ACCELERATOR
-    instead: one batched exact-kernel (content-decided kills) launch per
-    capacity bucket over the failure prefixes after the ladder drains —
-    no CPU sweeps on the happy path, which matters on single-core hosts
-    where the worker sweeps time-share the driver's core; the rare
-    disagreeing/lossy lane falls back to the bounded CPU sweep.
-
-    Escalation is about CAPACITY: each ladder stage re-runs only the
-    still-lossy histories wider — and with ``carry_frontier`` (the
-    default, round 5) an async rung RESUMES each straggler from its
-    saved exact pre-loss frontier at its failure barrier instead of
-    re-running the whole history: the verified prefix is never re-paid,
-    and the rung's tick budget shrinks to the max REMAINING barriers.
-    Soundness is unchanged (the snapshot is taken before any loss, so
-    refutations keep their "no loss anywhere" meaning and are still
-    sweep-confirmed).  ``exact_escalation`` optionally appends
-    stages on the in-round-domination kernel (frontier_update; ~10x
-    slower per lane but content-exact, so its refutations are final);
-    wide stages sub-batch automatically.  Behavior change (round 3):
-    ``exact_escalation=None`` now means NO exact stages — it used to mean
-    one stage at 4x the last batch capacity.  Refutation soundness moved
-    to the confirmation sweep, and the wider default batch ladder covers
-    the capacity range; but callers with ``cpu_fallback=False`` that
-    relied on the implicit exact stage to resolve capacity-bound lanes
-    may see more "unknown"s and should pass ``exact_escalation``
-    explicitly.  Remaining unknowns fall back to the CPU config-set
-    sweep when ``cpu_fallback``: a history the last rung leaves lossy is
-    swept whole in the confirmation workers as soon as it leaves the
-    ladder, where refutations are confirmed there (and no mesh rescue
-    runs); the rest in this process after the ladder.  Returns one
-    knossos-shaped result per history, in order.
+    a constructive witness), and exact refutations are final.  Async
+    refutations are decided on 64-bit row hashes, so with
+    ``confirm_refutations`` (the default, the "never an unconfirmed
+    False" contract) each is confirmed by the exact CPU config-set sweep
+    in worker processes, CONCURRENTLY with the remaining rungs, so
+    soundness costs almost no wall clock.  A sweep past
+    ``confirm_max_configs`` leaves "unknown" (never a wrong False); a
+    sweep that disagrees (the ~1e-13 collision case) wins.  Remaining
+    unknowns fall back to the CPU config-set sweep when ``cpu_fallback``:
+    a history the last rung leaves lossy is swept whole in the
+    confirmation workers as it leaves the ladder when refutations are
+    confirmed there (and no mesh rescue runs); the rest in this process
+    after the ladder.  Returns one knossos-shaped result per history,
+    in order.
 
     ``dedup_backend`` selects the per-round frontier dedup backend for
     every rung — "sort" (multi-key hash sort) or "bucket" (packed radix
     buckets; see jepsen_tpu.ops.hashing).  None resolves through the
     JEPSEN_TPU_DEDUP_BACKEND env var, then the module default.  Verdict
-    semantics are backend-independent: fast-engine refutations are
+    semantics are backend-independent: async refutations are
     hash-decided (and confirmed) either way, exact-engine kills are
     content-decided either way.  (The greedy rung walks a single
     configuration — no frontier, nothing to dedup — so the backend
@@ -442,7 +429,9 @@ def batch_analysis(
     yields verdicts identical to an uninterrupted run.  On resume the
     SAVED config wins over the caller's ladder arguments (verdict
     identity requires the original ladder), and a checkpoint whose
-    history fingerprint doesn't match is ignored with a warning.
+    history fingerprint doesn't match, or whose config pins a removed
+    ladder path (``removed_path``), is quarantined and the run starts
+    fresh.
     ``deadline`` (seconds or a faults.Deadline) bounds wall clock: it
     is polled at stage boundaries; on expiry the ladder checkpoints,
     marks the remaining packs ``unknown`` with cause
@@ -455,8 +444,8 @@ def batch_analysis(
     any lane halving, so the sub-batch ladder engages only once spill
     is exhausted.  ``frontier_budget_mb`` (or the
     JEPSEN_TPU_FRONTIER_BUDGET_MB env var) caps the exact engine's
-    device frontier working set: the chunked exact paths (unsafe-shape
-    lanes and device-confirmation fallbacks) then host-spill overflow
+    device frontier working set: the chunked exact path (unsafe-shape
+    lanes) then host-spills overflow
     rows instead of going lossy (``ops.wgl.chunked_analysis``), and a
     history fixed memory still cannot decide returns ``unknown`` with a
     machine-readable undecidability report in its ``cause``
@@ -511,21 +500,15 @@ def batch_analysis(
         histories=len(histories), tensorizable=len(packs),
     )
 
-    if engine not in ("sync", "async"):
-        raise ValueError(f"unknown engine {engine!r}; expected 'sync' or 'async'")
-    if confirm_refutations not in (True, False, "device"):
+    if not isinstance(confirm_refutations, bool):
         raise ValueError(
-            f"unknown confirm_refutations {confirm_refutations!r}; "
-            "expected True (worker sweeps), False, or 'device'"
+            f"confirm_refutations must be a bool, got {confirm_refutations!r}"
         )
     capacities = [capacity] if isinstance(capacity, int) else list(capacity)
     batch_caps = [int(c) for c in capacities]
     if exact_escalation is None and not cpu_fallback:
-        # Behavior changed in round 3 (None used to mean one implicit
-        # exact stage at 4x the last batch capacity; now it means none).
-        # Callers without the CPU fallback are the ones who can observe
-        # the difference — as extra "unknown"s with no runtime signal —
-        # so give them one (advisor r4).
+        # Without the CPU fallback a capacity-bound history stays
+        # "unknown" with no other runtime signal: say so once.
         global _WARNED_EXACT_DEFAULT
         if not _WARNED_EXACT_DEFAULT:
             _WARNED_EXACT_DEFAULT = True
@@ -570,34 +553,39 @@ def batch_analysis(
                         reason=getattr(e, "report", None) and
                         e.report.get("reason") or "unreadable",
                         report=getattr(e, "report", None))
-        if restored is not None and restored["config"].get("fingerprint") != fp:
+        why = None
+        if restored is not None:
+            removed = removed_path(restored["config"])
+            if restored["config"].get("fingerprint") != fp:
+                # resuming against changed inputs could only produce
+                # wrong verdicts
+                reason, why = "fingerprint", "was written for other histories"
+            elif removed is not None:
+                reason, why = "removed-path", f"pins a removed path ({removed})"
+        if why is not None:
             # The stale pair is QUARANTINED aside, not merely ignored: a
             # later --resume against the same dir (now with the matching
             # histories again) must never pick the mismatched state back
             # up, and the checkpoint this fresh run is about to write
             # must not interleave with the old files.
             quarantined = _ckpt.quarantine(checkpoint_dir,
-                                           reason="stale-fingerprint")
+                                           reason=f"stale-{reason}")
             logger.warning(
-                "checkpoint in %s was written for different histories; "
-                "running fresh (resuming against changed inputs could "
-                "only produce wrong verdicts); stale files quarantined: "
-                "%s", checkpoint_dir, quarantined)
+                "checkpoint in %s %s; running fresh; stale files "
+                "quarantined: %s", checkpoint_dir, why, quarantined)
             obs.counter("fault.checkpoint.quarantined",
-                        reason="fingerprint", files=quarantined)
-            obs.counter("fault.checkpoint.mismatch", reason="fingerprint")
+                        reason=reason, files=quarantined)
+            obs.counter("fault.checkpoint.mismatch", reason=reason)
             restored = None
         if restored is not None:
             # The saved config wins: verdict identity requires the
             # original ladder, and the CLI resume path can't know the
             # original kwargs.
             cfg = restored["config"]
-            engine = cfg.get("engine", engine)
             batch_caps = [int(c) for c in cfg.get("capacity", batch_caps)]
             exact_caps = [int(c) for c in cfg.get("exact_escalation", exact_caps)]
             rounds = int(cfg.get("rounds", rounds))
             greedy_first = bool(cfg.get("greedy_first", greedy_first))
-            carry_frontier = bool(cfg.get("carry_frontier", carry_frontier))
             dedup = cfg.get("dedup", dedup)
             confirm_refutations = cfg.get(
                 "confirm_refutations", confirm_refutations)
@@ -622,17 +610,19 @@ def batch_analysis(
                                 r,
                                 [{"event": "checkpoint.restored",
                                   "complete": True, "stage": start_stage}],
-                                engine={"engine": engine,
+                                engine={"engine": "async",
                                         "dedup_backend": dedup},
                             )
                         results[i] = r
                 return [r if r is not None else {"valid?": "unknown"}
                         for r in results]
+    # "engine" and "carry_frontier" are constants, recorded so records
+    # keep their bytes and removed_path can tell older ones apart
     config = {
-        "engine": engine, "capacity": list(batch_caps),
+        "engine": "async", "capacity": list(batch_caps),
         "exact_escalation": list(exact_caps), "rounds": int(rounds),
         "greedy_first": bool(greedy_first),
-        "carry_frontier": bool(carry_frontier), "dedup": dedup,
+        "carry_frontier": True, "dedup": dedup,
         "confirm_refutations": confirm_refutations, "fingerprint": fp,
         "frontier_budget_mb": frontier_budget_mb,
     }
@@ -645,7 +635,7 @@ def batch_analysis(
     # fallbacks, and fault events produced each verdict.
     # ------------------------------------------------------------------
     prov_cfg = {k: v for k, v in config.items() if k != "fingerprint"}
-    prov_engine: dict = {"engine": engine, "dedup_backend": dedup,
+    prov_engine: dict = {"engine": "async", "dedup_backend": dedup,
                         "greedy_first": bool(greedy_first)}
     if dedup == "pallas":
         try:
@@ -780,14 +770,14 @@ def batch_analysis(
         with host arrays of len(sub).  ``sub_resumes[j]`` optionally
         carries lane j's saved (bsnap, state, fok, fcr, alive) frontier
         from the previous rung — the lane resumes there instead of
-        re-running the whole history (round 5: carried-frontier
-        escalation).  ``snap`` is the async engine's resume snapshot as
-        ON-DEVICE arrays (bsnap, state, fok, fcr, alive), or None: the
-        stage loop fetches rows host-side only for lanes that actually
-        stay pending AND have a later async rung to resume on — each
-        ``np.asarray`` here is a device-to-host round-trip, and fetching every
-        lane's full padded frontier after every rung was measured at
-        ~0.8 s on the bench ladder (chip ablation, round 5)."""
+        re-running the whole history.  ``snap`` is the async engine's
+        resume snapshot as ON-DEVICE arrays (bsnap, state, fok, fcr,
+        alive), or None: the stage loop fetches rows host-side only for
+        lanes that actually stay pending AND have a later async rung to
+        resume on — each ``np.asarray`` here is a device-to-host
+        round-trip, and fetching every lane's full padded frontier after
+        every rung was measured at ~0.8 s on the bench ladder (chip
+        ablation, round 5)."""
         B, P, G = bucket_geometry(
             max(p["B"] for p in sub),
             max(p["P"] for p in sub),
@@ -893,15 +883,10 @@ def batch_analysis(
             launch_acc["_key"] = (sub[0]["step"], "async", batch_cap, T, B, P, G, W, n_pad, dedup)
             runner = wgl.async_runner(sub[0]["step"], batch_cap, T, B, P, G, W, dedup)
             valid, failed_at, lossy, peak, bsnap, sst, sfo, sfc, sal = runner(*a_args)
-            if carry_frontier:
-                # keep the snapshot ON-DEVICE; the stage loop fetches
-                # only the still-pending rows (and only when a later
-                # async rung exists to resume on)
-                snap = (bsnap, sst, sfo, sfc, sal)
-        elif st_engine == "sync":
-            launch_acc["_key"] = (sub[0]["step"], "sync", batch_cap, int(rounds), B, P, G, W, n_pad, dedup)
-            runner = wgl.batched_runner(sub[0]["step"], batch_cap, int(rounds), P, G, W, dedup)
-            valid, failed_at, lossy, peak = runner(*args)
+            # keep the snapshot ON-DEVICE; the stage loop fetches only
+            # the still-pending rows (and only when a later async rung
+            # exists to resume on)
+            snap = (bsnap, sst, sfo, sfc, sal)
         else:  # "exact": content-compare dedup/domination — may refute
             launch_acc["_key"] = (sub[0]["step"], "exact", batch_cap, int(rounds), B, P, G, W, n_pad, dedup)
             runner = wgl.exact_batched_runner(sub[0]["step"], batch_cap, int(rounds), P, G, W, dedup)
@@ -933,7 +918,7 @@ def batch_analysis(
             **mem, **stage_attrs, **extra,
         )
 
-    stages = [(engine, c) for c in batch_caps] + [("exact", c) for c in exact_caps]
+    stages = [("async", c) for c in batch_caps] + [("exact", c) for c in exact_caps]
     if greedy_first and stages:
         stages = [("greedy", 1)] + stages
     pending = list(range(len(packs)))
@@ -944,15 +929,13 @@ def batch_analysis(
     # worker-pool process boundary (the worker itself records nothing;
     # its submit/resolve bracket in this process carries the trace).
     confirm_futs: dict = {}
-    device_confirms: list[tuple] = []  # (pack idx, failed_at, cap, result)
     confirm_degraded: set[int] = set()  # hist idxs whose confirmation hit the deadline
     if restored is not None:
         # Re-enter the ladder where the checkpoint left it: verdicts so
         # far (including the pending lanes' unknown placeholders), the
         # pending set, and each pending lane's carried-frontier resume
         # snapshot.  In-flight worker confirmations are RESUBMITTED (the
-        # old futures died with the old process); queued device
-        # confirmations re-queue as they were.
+        # old futures died with the old process).
         pack_of = {i: k for k, i in enumerate(idxs)}
         for i, r in restored["results"].items():
             if 0 <= i < len(results):
@@ -974,12 +957,6 @@ def batch_analysis(
                 obs.capture(),
             )
             results[i] = info["res"]
-        for e in restored["device_confirms"]:
-            if e["i"] in pack_of:
-                device_confirms.append(
-                    (pack_of[e["i"]], int(e["failed_at"]), int(e["cap"]), e["res"])
-                )
-                results[e["i"]] = e["res"]
 
     #: per-pack rung cursor: stages[rungs[k]] is pack k's NEXT rung.
     #: Every initial pack starts (or resumes) at the same rung, so
@@ -1021,10 +998,6 @@ def batch_analysis(
                     i: {"res": res, "op_pos": op_pos}
                     for i, (_p, _f, res, _t, op_pos, _c) in confirm_futs.items()
                 },
-                device_confirms=[
-                    {"i": idxs[k], "failed_at": fat, "cap": cap, "res": res}
-                    for k, fat, cap, res in device_confirms
-                ],
                 resumes={idxs[k]: resumes[k] for k in pending if k in resumes},
                 rungs={idxs[k]: rungs.get(k, next_stage) for k in pending},
                 complete=complete,
@@ -1130,8 +1103,7 @@ def batch_analysis(
     #: the serial in-process CPU fallback after the ladder.
     mesh_rescue = (dedup == "pallas" and mesh is not None
                    and mesh.devices.size > 1)
-    sweep_undecided = (cpu_fallback and confirm_refutations is True
-                       and not mesh_rescue)
+    sweep_undecided = cpu_fallback and confirm_refutations and not mesh_rescue
     n_swept = 0  # undecided packs handed to the worker sweeps
     #: Lowest-rung-first selection + rung-0 joiner admission could defer
     #: an escalated member forever under a steady arrival stream; a
@@ -1238,7 +1210,7 @@ def batch_analysis(
             stage=si, engine=st_engine, capacity=batch_cap,
             lanes=len(group), dedup=dedup,
         )
-        if dedup == "pallas" and st_engine in ("async", "sync") and group:
+        if dedup == "pallas" and st_engine == "async" and group:
             # Fused-kernel rungs carry the kernel's tile/VMEM occupancy
             # on their ladder.stage rows (estimate at the rung's widest
             # pack shape — stage_occupancy is pure arithmetic), plus an
@@ -1321,17 +1293,15 @@ def batch_analysis(
         # Bound total frontier rows per launch so wide-capacity stages
         # sub-batch instead of faulting the TPU worker (observed at
         # capacity*lanes ≳ 64k on the exact engine, whose sort and
-        # domination buffers are ~10x the fast kernel's per-lane
-        # footprint; fast engines get a proportionally larger budget).
-        # The carried-frontier snapshot doubles the async kernel's
-        # resident per-lane frontier, so its budget halves to keep the
-        # old resident bound (re-measure the true threshold on-chip).
+        # domination buffers are ~10x the async kernel's per-lane
+        # footprint, so the async and greedy stages get larger budgets;
+        # re-measure the true thresholds on-chip).
         if st_engine == "exact":
             budget = _EXACT_LANE_BUDGET
-        elif st_engine == "async" and carry_frontier:
+        elif st_engine == "async":
             budget = _CARRY_LANE_BUDGET
         else:
-            budget = _FAST_LANE_BUDGET
+            budget = _GREEDY_LANE_BUDGET
         # Carried-frontier fetch (round 5): resume snapshots leave the
         # device only for lanes that STAY pending, and only when a later
         # async rung exists to resume them — each lane's pre-loss
@@ -1343,10 +1313,8 @@ def batch_analysis(
         # across sub-batches.  The unconditional full-batch fetch this
         # replaces measured ~0.8 s of device-to-host round-trips on the bench
         # ladder (chip ablation, round 5).
-        fetch_snaps = (
-            st_engine == "async" and carry_frontier
-            and any(e == "async" for e, _ in stages[si + 1:])
-        )
+        fetch_snaps = st_engine == "async" and any(
+            e == "async" for e, _ in stages[si + 1:])
         lane_out: dict[int, tuple] = {}  # pack idx -> (valid, fat, lossy, peak)
         degraded: list[tuple[int, str]] = []  # (pack idx, cause)
 
@@ -1368,10 +1336,8 @@ def batch_analysis(
             part's snapshot is ever device-resident, preserving the
             lane budget's resident-row bound)."""
             nonlocal budget_scale
-            sub_res = (
-                [resumes.get(k) for k in part]
-                if (st_engine == "async" and carry_frontier) else None
-            )
+            sub_res = ([resumes.get(k) for k in part]
+                       if st_engine == "async" else None)
             ctx = dict(
                 what=f"ladder.{st_engine}", stage=si, engine=st_engine,
                 capacity=batch_cap, lanes=len(part),
@@ -1516,26 +1482,19 @@ def batch_analysis(
                 op_pos = int(packs[k]["bar_opid"][int(fat_k)])
                 op = histories[i][op_pos]
                 res = {"valid?": False, "op": op, "kernel": stats}
+                final = st_engine == "exact" or not confirm_refutations
+                # "True" names the worker confirmation in the decision
+                # path: evidence digests compare these strings
                 _pv(i, "ladder.stage", stage=si, engine=st_engine,
                     capacity=batch_cap, outcome="refuted",
-                    confirm=("none" if st_engine == "exact"
-                             or not confirm_refutations
-                             else str(confirm_refutations)))
-                if st_engine == "exact" or not confirm_refutations:
+                    confirm="none" if final else "True")
+                if final:
                     # content-decided kills (or the caller opted out):
                     # the refutation is final
                     results[i] = res
                     _notify(i)
-                elif confirm_refutations == "device":
-                    # confirm on the accelerator: queue for one batched
-                    # exact-kernel launch over the failure prefix after
-                    # the ladder drains (no CPU sweeps at all on the
-                    # happy path — the drain tail was the 1-core host's
-                    # serial sweeps)
-                    device_confirms.append((k, int(fat_k), batch_cap, res))
-                    results[i] = res  # placeholder; resolved below
                 else:
-                    # fast-engine refutation: hash-dedup could in
+                    # async refutation: hash-dedup could in
                     # principle have killed a distinct config, so the
                     # exact CPU sweep confirms it — in a worker
                     # process, concurrent with the remaining stages.
@@ -1656,11 +1615,8 @@ def batch_analysis(
         exhausted = still_exhausted
 
     if exhausted:
-        # The lanes the whole ladder failed to resolve: close the
-        # documented "extra unknowns with no runtime signal" gap — a final
-        # gauge plus an attributable cause in each unknown result (these
-        # are exactly the lanes a pre-round-3 implicit exact stage might
-        # have resolved when cpu_fallback is off).
+        # The lanes the whole ladder failed to resolve: a final gauge
+        # plus an attributable cause in each unknown result.
         obs.gauge("ladder.unknowns_remaining", len(exhausted), final=True)
         if exact_caps:
             note = (
@@ -1680,147 +1636,6 @@ def batch_analysis(
             if r is not None and r.get("valid?") == "unknown" and r.get("cause"):
                 r["cause"] = f"{r['cause']}; {note}"
 
-    device_resolved: set[int] = set()
-
-    def _finish_confirmation(k: int, fat: int, res: dict, exact_died: bool) -> None:
-        """Resolve one device-mode confirmation: an exact lossless death
-        makes the refutation final; otherwise (collision artifact or
-        loss) the bounded CPU sweep decides (shared by the batched
-        launch and the unsafe-shape chunked fallback).  A deadline that
-        expires mid-confirmation degrades to unknown instead of
-        starting a sweep the budget can no longer cover."""
-        nonlocal deadline_tripped
-        i = idxs[k]
-        device_resolved.add(i)
-        if exact_died:
-            res["confirmed?"] = True
-            _pv(i, "confirm.device", outcome="refuted-final")
-            results[i] = res
-            _notify(i)
-            return
-        if deadline is not None and deadline.expired():
-            deadline_tripped = True
-            _pv(i, "fault.deadline", at="device-confirm")
-            results[i] = {
-                "valid?": "unknown",
-                "cause": ("device refutation; deadline-exceeded before "
-                          "exact confirmation"),
-                "kernel": res.get("kernel"),
-            }
-            return
-        op_pos = int(packs[k]["bar_opid"][fat])
-        cpu_res = wgl_cpu.sweep_analysis(
-            model, histories[i], max_configs=confirm_max_configs,
-            stop_at_index=op_pos,
-        )
-        results[i] = _resolve_confirmation(res, cpu_res)
-        _pv(i, "confirm.resolved", mode="device-sweep",
-            outcome=_prov.verdict_str(results[i].get("valid?")))
-        _notify(i)
-
-    if device_confirms and deadline is not None and deadline.expired():
-        # The budget died before the exact confirmations ran: an
-        # unconfirmed fast-engine False must never be reported, so each
-        # one degrades to unknown.  The descriptors are in the
-        # checkpoint — a resume finishes the confirmations.  A stage
-        # trip already saved a resumable checkpoint (which INCLUDES
-        # these descriptors); overwriting it here would bake the
-        # pending lanes' deadline causes in as final results and
-        # destroy their resume frontiers.
-        deadline_tripped = True
-        if not trip_checkpointed:
-            ck = _save_checkpoint(len(stages))
-            trip_checkpointed = ck is not None
-        else:
-            ck = _ckpt.json_path(checkpoint_dir) if checkpoint_dir else None
-        obs.event("fault.deadline", at="device-confirm",
-                  unresolved=len(device_confirms))
-        obs.counter("fault.deadline.trip")
-        note = f"; resumable checkpoint: {ck}" if ck else ""
-        for k, _fat, _cap, res in device_confirms:
-            _pv(idxs[k], "fault.deadline", at="device-confirm")
-            results[idxs[k]] = {
-                "valid?": "unknown",
-                "cause": (
-                    "device refutation; deadline-exceeded before exact "
-                    f"confirmation{note}"
-                ),
-                "kernel": res.get("kernel"),
-            }
-            no_fallback.add(idxs[k])
-        device_confirms = []
-    if device_confirms:
-        # One batched exact-engine launch per capacity bucket over the
-        # failure PREFIXES: content-decided kills make a lossless exact
-        # death a FINAL refutation.  The fast engine refuted losslessly,
-        # so (modulo the ~1e-13 hash-collision case) the true frontier
-        # fit its capacity; a surviving or lossy exact run IS that rare
-        # case and falls back to the exact CPU sweep.
-        _reset_launch_acc()
-        t_conf = time.perf_counter()
-        by_cap: dict[int, list[tuple]] = {}
-        for k, fat, cap, res in device_confirms:
-            by_cap.setdefault(cap, []).append((k, fat, res))
-        for cap, group in sorted(by_cap.items()):
-            masked = []
-            safe_group = []
-            lanes_cap = max(1, _EXACT_LANE_BUDGET // cap)
-            n_lanes = min(lanes_cap, len(group))
-            n_lanes = 1 << max(3, (n_lanes - 1).bit_length())
-            for k, fat, res in group:
-                p = dict(packs[k])
-                act = p["bar_active"].copy()
-                act[fat + 1 :] = False  # refutation needs only the prefix
-                p["bar_active"] = act
-                if wgl.exact_scan_safe(wgl.pad_B(p["B"]), cap, n_lanes):
-                    safe_group.append((k, fat, res))
-                    masked.append(p)
-                    continue
-                # Past the exact runner's measured fault boundary (see
-                # wgl.exact_scan_safe): confirm via the chunked exact
-                # path — short chunk scans, same content-decided kills.
-                # An exact no-loss death anywhere in the prefix is a
-                # final refutation; a surviving or lossy chunked run is
-                # the collision/loss case, resolved like the batched
-                # launch below.
-                _pv(idxs[k], "confirm.chunked-exact", capacity=cap)
-                r = wgl.chunked_analysis(
-                    model, histories[idxs[k]], p, [cap], rounds=int(rounds),
-                    fast=False, dedup_backend=dedup, deadline=deadline,
-                    frontier_budget_mb=frontier_budget_mb,
-                )
-                _finish_confirmation(k, fat, res, r["valid?"] is False)
-            group = safe_group
-            for s0 in range(0, len(group), lanes_cap):
-                sub = masked[s0 : s0 + lanes_cap]
-                ctx = dict(what="ladder.confirm", engine="exact",
-                           capacity=cap, lanes=len(sub))
-                try:
-                    gvalid, gfailed, glossy, _pk, _rs = faults.call_with_retry(
-                        lambda: _launch("exact", cap, sub), ctx
-                    )
-                except faults.LaunchFailure as lf:
-                    # no halving here: the bounded CPU sweep is the
-                    # natural degradation for a failed confirm launch —
-                    # it decides each refutation exactly, just slower
-                    obs.counter(
-                        "fault.launch.degraded", what="ladder.confirm",
-                        capacity=cap, lanes=len(sub),
-                        error=faults.describe(lf.cause),
-                    )
-                    for (k, fat, res) in group[s0 : s0 + lanes_cap]:
-                        _finish_confirmation(k, fat, res, False)
-                    continue
-                for (k, fat, res), v, f2, lz in zip(
-                    group[s0 : s0 + lanes_cap], gvalid, gfailed, glossy
-                ):
-                    _finish_confirmation(k, fat, res, f2 >= 0 and not lz)
-        obs.span_event(
-            "ladder.confirm.device", time.perf_counter() - t_conf,
-            refutations=len(device_confirms), launches=launch_acc["launches"],
-        )
-        device_confirms = []  # resolved; keep them out of later checkpoints
-
     if cpu_fallback:
         t_fb = time.perf_counter()
         n_fb = 0
@@ -1835,8 +1650,8 @@ def batch_analysis(
                     obs.event("fault.deadline", at="cpu-fallback")
                 break
             if (r is not None and r["valid?"] == "unknown"
-                    and i not in confirm_futs and i not in device_resolved
-                    and i not in early_confirmed and i not in no_fallback):
+                    and i not in confirm_futs and i not in early_confirmed
+                    and i not in no_fallback):
                 # The config-set sweep, not the DFS: DFS backtracking goes
                 # exponential on exactly the histories that overflow the
                 # kernel (info-heavy invalid ones); the sweep is the same

@@ -176,7 +176,6 @@ def save(
     results: Mapping[int, Mapping],
     pending: Sequence[int],
     confirms: Mapping[int, Mapping] | None = None,
-    device_confirms: Sequence[Mapping] | None = None,
     resumes: Mapping[int, tuple] | None = None,
     rungs: Mapping[int, int] | None = None,
     complete: bool = False,
@@ -184,14 +183,12 @@ def save(
     """Atomically persist one stage boundary's state; returns the json
     path.  ``resumes`` maps history index -> (bsnap, state, fok, fcr,
     alive); ``confirms`` maps history index -> {"res", "op_pos"} for
-    in-flight worker confirmations (resubmitted on resume);
-    ``device_confirms`` is the queued device-confirmation descriptors
-    [{"i", "failed_at", "cap", "res"}].  ``rungs`` optionally maps a
-    pending history index -> its NEXT ladder-stage index — continuous
-    batching admits members at rung boundaries, so pending members may
-    sit at different rungs; a member absent from the map resumes at
-    ``stage`` (the pre-continuous behavior, and what old checkpoints
-    decode to).  ``complete`` marks a finished run — resuming it
+    in-flight worker confirmations (resubmitted on resume).  ``rungs``
+    optionally maps a pending history index -> its NEXT ladder-stage
+    index — continuous batching admits members at rung boundaries, so
+    pending members may sit at different rungs; a member absent from
+    the map resumes at ``stage`` (the pre-continuous behavior, and what
+    old checkpoints decode to).  ``complete`` marks a finished run — resuming it
     returns the saved results without device work."""
     d = Path(d)
     d.mkdir(parents=True, exist_ok=True)
@@ -220,7 +217,6 @@ def save(
         "results": {str(i): r for i, r in (results or {}).items()},
         "pending": [int(i) for i in pending],
         "confirms": {str(i): c for i, c in (confirms or {}).items()},
-        "device_confirms": list(device_confirms or ()),
         "resumes": sorted(int(i) for i in resumes),
         "rungs": {str(i): int(r) for i, r in (rungs or {}).items()},
     }
@@ -474,13 +470,22 @@ def load(d) -> dict:
     confirms, resume tuples rebuilt from the npz.  Raises
     CheckpointError (with the durable layer's ``.report`` when
     applicable) on a missing, torn, corrupt, or unmigratable file;
-    corrupt json/npz pairs are quarantined aside before the raise."""
+    corrupt json/npz pairs are quarantined aside before the raise, and
+    so is a pair that queues device-side refutation confirmations (a
+    removed ladder path no resume can finish)."""
     p = json_path(d)
     try:
         rr = _durable.read_verified(p, KIND_LADDER)
     except _durable.DurableError as e:
         raise CheckpointError(str(e), e.report) from e
     doc = rr.payload
+    if doc.get("device_confirms"):
+        q = quarantine(d, reason="stale-removed-path")
+        raise CheckpointError(
+            f"{p} queues device-side refutation confirmations, a removed "
+            "ladder path",
+            {"artifact": KIND_LADDER, "path": str(p),
+             "reason": "removed-path", "quarantined_to": q})
     out = {
         "complete": bool(doc.get("complete")),
         "config": doc.get("config") or {},
@@ -488,7 +493,6 @@ def load(d) -> dict:
         "results": {int(i): r for i, r in (doc.get("results") or {}).items()},
         "pending": [int(i) for i in doc.get("pending") or ()],
         "confirms": {int(i): c for i, c in (doc.get("confirms") or {}).items()},
-        "device_confirms": list(doc.get("device_confirms") or ()),
         "resumes": {},
         "rungs": {int(i): int(r) for i, r in (doc.get("rungs") or {}).items()},
         "path": str(p),

@@ -359,7 +359,6 @@ def test_checkpoint_roundtrip(tmp_path):
         results={0: {"valid?": True}, 1: {"valid?": "unknown", "cause": "x"}},
         pending=[3],
         confirms={2: {"res": {"valid?": False}, "op_pos": 9}},
-        device_confirms=[{"i": 4, "failed_at": 5, "cap": 64, "res": {"valid?": False}}],
         resumes=resumes,
     )
     out = ckpt.load(tmp_path)
@@ -367,7 +366,6 @@ def test_checkpoint_roundtrip(tmp_path):
     assert out["results"][0]["valid?"] is True
     assert out["pending"] == [3]
     assert out["confirms"][2]["op_pos"] == 9
-    assert out["device_confirms"][0]["i"] == 4
     bs, st, fo, fc, al = out["resumes"][3]
     assert bs == 7 and st.tolist() == [0, 1, 2, 3]
     assert al.tolist() == [True, False, True, False]
@@ -446,6 +444,47 @@ def test_checkpoint_fingerprint_mismatch_runs_fresh(tmp_path):
         m.CASRegister(None), hists_b, checkpoint_dir=tmp_path, resume=True, **KW
     )
     assert [r["valid?"] for r in res] == expect_b
+
+
+@pytest.mark.parametrize("removed", [
+    {"config": {"engine": "sync"}},
+    {"config": {"confirm_refutations": "device"}},
+    {"config": {"carry_frontier": False}},
+    {"device_confirms": [
+        {"i": 1, "failed_at": 3, "cap": 16, "res": {"valid?": False}}]},
+], ids=["engine-sync", "confirm-device", "scratch-restart",
+        "device-confirms"])
+def test_checkpoint_naming_removed_path_runs_fresh(tmp_path, removed):
+    """A checkpoint that pins a removed ladder path (the barrier-scan
+    engine, device-side confirmation, scratch-restart rungs, or queued
+    device confirmations) is quarantined on resume and the run starts
+    fresh — never continued under the remaining ladder."""
+    from jepsen_tpu.store import durable
+
+    hists, _ = make_histories()
+    # a resumable checkpoint with ladder work left (deadline trips at
+    # stage 0), rewritten to name the removed path
+    pb.batch_analysis(m.CASRegister(None), hists, checkpoint_dir=tmp_path,
+                      deadline=faults.Deadline(0.0), **KW)
+    jp = ckpt.json_path(tmp_path)
+    rr = durable.read_verified(jp, ckpt.KIND_LADDER)
+    doc = dict(rr.payload)
+    doc["config"] = {**doc["config"], **removed.get("config", {})}
+    doc.update({k: v for k, v in removed.items() if k != "config"})
+    durable.write_record(jp, ckpt.KIND_LADDER, doc, files=rr.files)
+
+    res = pb.batch_analysis(
+        m.CASRegister(None), hists, checkpoint_dir=tmp_path, resume=True, **KW
+    )
+    assert list(tmp_path.glob(f"{ckpt.CKPT_JSON}.corrupt-*")), (
+        sorted(p.name for p in tmp_path.iterdir()))
+    for r in res:
+        events = [e["event"] for e in r["provenance"]["path"]]
+        assert "checkpoint.restored" not in events, events
+    assert [r["valid?"] for r in res] == [r["valid?"] for r in clean_run()]
+    # the fresh run sealed a main-path checkpoint of its own
+    saved = ckpt.load(tmp_path)
+    assert saved["complete"] and pb.removed_path(saved["config"]) is None
 
 
 # ---------------------------------------------------------------------------

@@ -108,6 +108,23 @@ def test_check_batch_ladder_bundles_verify_and_replay(tmp_path):
     assert ref["witness"]["type"] == "refutation"
 
 
+def test_replay_refuses_bundle_pinned_to_removed_engine(tmp_path):
+    """A bundle pinned to a removed ladder path (here the barrier-scan
+    "sync" engine) is not replayed under the remaining ladder and
+    called a match: replay reports an error naming the path."""
+    hist = valid_register_history(30, 3, seed=3, info_rate=0.1)
+    _checker().check_batch(_test_map(tmp_path), [hist], {})
+    (_, b), = _bundles(tmp_path)
+    assert provenance.replay_bundle(b)["ok"]
+    pinned = {**b, "engine": {**b["engine"], "engine": "sync"},
+              "config": {**b["config"], "engine": "sync"}}
+    rr = provenance.replay_bundle(pinned)
+    assert not rr["ok"], rr
+    assert rr["replayed"] is None
+    assert any("removed ladder path" in e and "sync" in e
+               for e in rr["errors"]), rr
+
+
 def test_degraded_unknown_replays_deterministically(tmp_path):
     """A deadline-tripped unknown records the trip on its decision path
     and replays under a pinned zero budget — the degraded outcome is

@@ -1,9 +1,9 @@
 """Soak the full batch ladder against the exact oracle.
 
 Random small histories in adversarial shapes (info-heavy, crash groups,
-cas, corruptions), checked in batches through the COMPLETE round-5
-ladder (greedy rung, carried frontiers, saturating prune, both
-confirmation modes, every DEDUP BACKEND — the ``dedup_backend`` axis
+cas, corruptions), checked in batches through the COMPLETE ladder
+(greedy rung, carried frontiers, saturating prune, worker confirmation
+on and off, every DEDUP BACKEND — the ``dedup_backend`` axis
 randomizes sort vs bucket vs pallas per batch) and compared
 verdict-by-verdict against ``wgl_cpu.sweep_analysis``.  Any non-unknown
 disagreement is a soundness bug — print it and exit 1.
@@ -117,13 +117,12 @@ def main() -> int:
                 if rng.random() < 0.5:
                     hist = corrupt(hist, seed=rng.randrange(1 << 30))
             hists.append(hist)
-        confirm = rng.choice([True, "device"])
+        confirm = rng.choice([True, False])
         dedup = rng.choice(axis_pool)
         results = batch_analysis(
             model, hists, capacity=(rng.choice([16, 32, 64]), 256),
             cpu_fallback=False, exact_escalation=(),
             confirm_refutations=confirm,
-            carry_frontier=rng.random() < 0.7,
             greedy_first=rng.random() < 0.8,
             dedup_backend=dedup,
         )
