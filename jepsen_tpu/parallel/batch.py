@@ -103,16 +103,58 @@ def bucket_geometry(B: int, P: int, G: int) -> tuple[int, int, int]:
     )
 
 
-def padded_batch(n: int, mesh: Mesh | None = None) -> int:
+#: the rung capacity from which a launch pads its batch axis to its
+#: live lanes alone: a lane of 512 rows or more fills the chip, so each
+#: padding lane (a copy of the last live one) costs a live lane's work.
+WIDE_CAPACITY = 512
+
+#: the rung capacity from which a launch holds one lane a device: there
+#: a launch's time grows about linearly with its lanes, so lanes launched
+#: one after another cost no more than together, and every launch of
+#: the rung runs one compiled shape whatever its lane count (a shape per
+#: lane count is a compile for each new count a workload brings).
+SERIAL_CAPACITY = 4096
+
+
+def padded_batch(n: int, mesh: Mesh | None = None,
+                 capacity: int | None = None,
+                 pad_to: int | None = None) -> int:
     """The padded batch-axis size a launch of ``n`` lanes runs at: the
-    next power of two (floor 8), rounded up to a mesh multiple — the
-    same quantity _launch_impl pads to, exposed so the serving layer can
-    report true batch occupancy / padding waste."""
-    n_pad = 1 << max(3, (n - 1).bit_length())
+    next power of two, rounded up to a mesh multiple, and at least the
+    pinned width ``pad_to`` (continuous batching).  Below
+    ``WIDE_CAPACITY`` rows a lane (and with ``capacity=None``) the
+    power of two is at least 8, so small launches share one compiled
+    shape; at a wide rung it is the lanes' own.  The launch pads to
+    exactly this, so the stage loop's occupancy, the exact guard and
+    the serving layer's padding waste read it too."""
+    floor = 0 if capacity is not None and capacity >= WIDE_CAPACITY else 3
+    n_pad = 1 << max(floor, (n - 1).bit_length())
     if mesh is not None:
         shard = mesh.devices.size
         n_pad = ((n_pad + shard - 1) // shard) * shard
+    if pad_to is not None and pad_to > n_pad:
+        n_pad = int(pad_to)
     return n_pad
+
+
+def _launch_shape(sub: Sequence[dict],
+                  resumes: Sequence[tuple | None] | None = None
+                  ) -> tuple[int, int, int, int | None]:
+    """The bucket geometry (B, P, G) a launch of the packs ``sub``
+    stacks to and, where the lanes' ``resumes`` are given (the async
+    engine's: a lane resumed from ``resumes[j]`` skips its verified
+    prefix), their most remaining barriers rounded up to a power of two
+    >= 32, which the async tick budget reads; else None."""
+    B, P, G = bucket_geometry(
+        max(p["B"] for p in sub),
+        max(p["P"] for p in sub),
+        max(p["G"] for p in sub),
+    )
+    if resumes is None:
+        return B, P, G, None
+    rem = max(1, max(int(p["bar_active"].sum()) - (r[0] if r else 0)
+                     for p, r in zip(sub, resumes)))
+    return B, P, G, 1 << max(5, (rem - 1).bit_length())
 
 
 def greedy_fastpath(model: m.Model, packed: Sequence[dict],
@@ -145,9 +187,7 @@ def greedy_fastpath(model: m.Model, packed: Sequence[dict],
     n = len(packed)
     # ``pad_to`` pins the batch axis to the caller's fixed serving
     # width: every wave size then re-hits ONE compiled greedy kernel.
-    n_pad = padded_batch(n, mesh)
-    if pad_to is not None and pad_to > n_pad:
-        n_pad = int(pad_to)
+    n_pad = padded_batch(n, mesh, capacity=1, pad_to=pad_to)
     n_actives = np.array([p["bar_active"].sum() for p in packed], np.int32)
     if n_pad != n:
         for k in stacked:
@@ -717,20 +757,25 @@ def batch_analysis(
 
     def _launch(st_engine: str, batch_cap: int, sub: list[dict],
                 sub_resumes: list[tuple | None] | None = None,
-                pad_to: int | None = None, retry: bool = False):
+                pad_to: int | None = None, retry: bool = False,
+                shape: tuple | None = None):
         """Instrumented wrapper over the kernel launch: times the launch,
         classifies it compile (fresh shape bucket) vs execute, samples
         the post-launch device-buffer footprint (the stage's memory
         high-water mark), and emits a ladder.launch telemetry span.
         ``retry`` marks a reduced-size OOM-halved / spill-retry launch —
         excluded from the watchdog's launch-time EWMA baseline
-        (faults.record_launch_seconds)."""
+        (faults.record_launch_seconds).  The span's ``padded`` is the
+        lane slots launched: ``lanes`` and their padding copies.
+        ``shape`` is _launch_impl's."""
+        n_pad = padded_batch(len(sub), mesh, batch_cap, pad_to)
         with obs.span(
             "ladder.launch", engine=st_engine, capacity=batch_cap,
-            lanes=len(sub), devices=_dev_ids,
+            lanes=len(sub), padded=n_pad, devices=_dev_ids,
         ) as sp:
             t0 = time.perf_counter()
-            out = _launch_impl(st_engine, batch_cap, sub, sub_resumes, pad_to)
+            out = _launch_impl(st_engine, batch_cap, sub, sub_resumes, n_pad,
+                               shape)
             dt = time.perf_counter() - t0
             # Feed the process launch-time EWMA the serving layer's
             # hung-launch watchdog derives its wall-clock caps from
@@ -763,38 +808,36 @@ def batch_analysis(
         return out
 
     def _launch_impl(st_engine: str, batch_cap: int, sub: list[dict],
-                     sub_resumes: list[tuple | None] | None = None,
-                     pad_to: int | None = None):
-        """Stack ``sub`` to common bucket shapes and run one vmapped
-        kernel launch; returns (valid, failed_at, lossy, peak, snap)
-        with host arrays of len(sub).  ``sub_resumes[j]`` optionally
-        carries lane j's saved (bsnap, state, fok, fcr, alive) frontier
-        from the previous rung — the lane resumes there instead of
-        re-running the whole history.  ``snap`` is the async engine's
-        resume snapshot as ON-DEVICE arrays (bsnap, state, fok, fcr,
-        alive), or None: the stage loop fetches rows host-side only for
+                     sub_resumes: list[tuple | None] | None, n_pad: int,
+                     shape: tuple | None = None):
+        """Stack ``sub`` to common bucket shapes, pad the batch axis to
+        ``n_pad`` lanes (``padded_batch``) and run one vmapped kernel
+        launch.  ``shape``, where given, is ``_launch_shape`` of the
+        rung's whole group: a part of it then runs the compiled shape
+        and tick budget the whole group's launch would have, so its
+        lanes' outcomes are the same.  Returns (valid, failed_at, lossy,
+        peak, snap) with host arrays of len(sub).  ``sub_resumes[j]``
+        optionally carries lane j's saved (bsnap, state, fok, fcr,
+        alive) frontier from the previous rung — the lane resumes there
+        instead of re-running the whole history.  ``snap`` is the async
+        engine's resume snapshot as ON-DEVICE arrays (bsnap, state, fok,
+        fcr, alive), or None: the stage loop fetches rows host-side only for
         lanes that actually stay pending AND have a later async rung to
         resume on — each ``np.asarray`` here is a device-to-host
         round-trip, and fetching every lane's full padded frontier after
         every rung was measured at ~0.8 s on the bench ladder (chip
         ablation, round 5)."""
-        B, P, G = bucket_geometry(
-            max(p["B"] for p in sub),
-            max(p["P"] for p in sub),
-            max(p["G"] for p in sub),
-        )
+        B, P, G, b_rem = shape or _launch_shape(sub, sub_resumes)
         stacked = _stack(sub, B, P, G)
         n = len(sub)
-        # Pad the batch axis to a power of two (and a mesh multiple) so the
-        # vmapped kernel compiles once per bucket, not once per batch size.
-        # ``pad_to`` (continuous batching) pins the width HIGHER — every
-        # rung of a served ladder launches at one fixed batch axis, so
-        # membership churn (joiners, resolved lanes) never changes the
-        # compiled shape mid-service: an underfull rung costs padded
-        # lanes (~replicated rows), never an XLA compile.
-        n_pad = padded_batch(n, mesh)
-        if pad_to is not None and pad_to > n_pad:
-            n_pad = int(pad_to)
+        # Padding lanes repeat the last live lane and are sliced off.
+        # Narrow rungs pad to a power of two >= 8 so the vmapped kernel
+        # compiles once per bucket, not once per batch size; wide rungs
+        # pad to their lanes' own power of two, as there each copy costs
+        # a live lane's work.  A pinned ``pad_to`` (continuous batching)
+        # widens every rung of a served ladder to one fixed batch axis,
+        # so membership churn (joiners, resolved lanes) never changes
+        # the compiled shape mid-service.
         if n_pad != n:
             for k in stacked:
                 if k in ("slot_lane", "slot_onehot"):
@@ -859,8 +902,6 @@ def batch_analysis(
             # Tick budget from the MAX REMAINING barriers, not the padded
             # B: resumed lanes skip their verified prefix, so the budget
             # (and the stage's worst-case wall clock) shrinks with it.
-            b_rem = int(max(1, (n_actives - bptr0[:n]).max()))
-            b_rem = 1 << max(5, (b_rem - 1).bit_length())
             T = wgl.async_ticks(min(b_rem, B), batch_cap)
             if n_pad != n:
                 n_actives = np.concatenate([n_actives, np.repeat(n_actives[-1:], n_pad - n)])
@@ -1090,6 +1131,28 @@ def batch_analysis(
     pad_lanes = getattr(admission, "pad_lanes", None)
     pad_lanes = int(pad_lanes) if pad_lanes else None
 
+    def _serial(batch_cap: int) -> bool:
+        """Whether the rung launches one lane a device at a time
+        (SERIAL_CAPACITY); not where a pinned width makes every launch
+        that wide anyway."""
+        return batch_cap >= SERIAL_CAPACITY and pad_lanes is None
+
+    def _lanes_per_launch(budget_rows: float, batch_cap: int) -> int:
+        """The most lanes one launch of a rung holds: the frontier-row
+        budget's share, and on a serial rung one a device."""
+        lanes = max(1, int(budget_rows) // batch_cap)
+        if _serial(batch_cap):
+            lanes = min(lanes, mesh.devices.size if mesh is not None else 1)
+        return lanes
+
+    def _pinned_width(lanes_cap: int) -> int | None:
+        """A launch's pinned batch axis: the fixed width clamped to the
+        stage's chunk lane budget, so pinning the shape never exceeds
+        the resident-row bound; None where nothing pins it."""
+        if pad_lanes is None:
+            return None
+        return min(pad_lanes, padded_batch(lanes_cap, mesh))
+
     #: OOM halvings shrink the stage lane budget for the REST of the run
     #: (the device that OOM'd once at a shape will OOM again; re-probing
     #: it every stage would pay the fault each time).
@@ -1263,12 +1326,13 @@ def batch_analysis(
         if st_engine == "exact":
             safe = []
             exact_ladder = [c for e, c in stages[si:] if e == "exact"]
-            # the launch pads its batch axis to a power of two >= 8, so
-            # the guard sees the PADDED lane count the kernel actually
-            # holds resident (the fault grid is single-lane; vmap
-            # multiplies the live buffers by the lane count)
-            n_lanes = min(max(1, _EXACT_LANE_BUDGET // batch_cap), len(group))
-            n_lanes = 1 << max(3, (n_lanes - 1).bit_length())
+            # the guard sees the PADDED lane count of the stage's widest
+            # launch, the one the kernel actually holds resident (the
+            # fault grid is single-lane; vmap multiplies the live
+            # buffers by the lane count)
+            lanes_cap = _lanes_per_launch(_EXACT_LANE_BUDGET, batch_cap)
+            n_lanes = padded_batch(min(lanes_cap, len(group)), mesh,
+                                   batch_cap, _pinned_width(lanes_cap))
             for k in group:
                 if wgl.exact_scan_safe(
                         wgl.pad_B(packs[k]["B"]), batch_cap, n_lanes):
@@ -1317,6 +1381,15 @@ def batch_analysis(
             e == "async" for e, _ in stages[si + 1:])
         lane_out: dict[int, tuple] = {}  # pack idx -> (valid, fat, lossy, peak)
         degraded: list[tuple[int, str]] = []  # (pack idx, cause)
+        # A serial rung runs every launch at the whole group's shape and
+        # tick budget: one compiled shape, and each lane's outcome what
+        # one launch of the group would give it.
+        group_shape = None
+        if _serial(batch_cap):
+            group_shape = _launch_shape(
+                [packs[k] for k in group],
+                [resumes.get(k) for k in group]
+                if st_engine == "async" else None)
 
         def _launch_ft(part: list[int], pad_to: int | None = None,
                        retry: bool = False, spilled: bool = False) -> None:
@@ -1346,7 +1419,7 @@ def batch_analysis(
                 out = faults.call_with_retry(
                     lambda: _launch(
                         st_engine, batch_cap, [packs[k] for k in part],
-                        sub_res, pad_to, retry,
+                        sub_res, pad_to, retry, group_shape,
                     ),
                     ctx,
                 )
@@ -1419,15 +1492,10 @@ def batch_analysis(
         s0 = 0
         launched_pad = 0
         while s0 < len(group):
-            lanes_cap = max(1, int(budget * budget_scale) // batch_cap)
+            lanes_cap = _lanes_per_launch(budget * budget_scale, batch_cap)
             part = group[s0 : s0 + lanes_cap]
-            pad_to = (
-                min(pad_lanes, padded_batch(lanes_cap, mesh))
-                if pad_lanes is not None else None
-            )
-            # the launch pads to MAX(natural pad, pinned pad) — mirror
-            # that here so reported slots never undercount live lanes
-            launched_pad += max(pad_to or 0, padded_batch(len(part), mesh))
+            pad_to = _pinned_width(lanes_cap)
+            launched_pad += padded_batch(len(part), mesh, batch_cap, pad_to)
             _launch_ft(part, pad_to)
             s0 += lanes_cap
         if admission is not None and hasattr(admission, "on_rung"):
